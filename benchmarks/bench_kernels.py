@@ -1,10 +1,10 @@
 """Kernel-path microbenches.
 
-On this CPU container the Pallas kernels run in interpret mode (Python) —
-wall times are NOT TPU-representative, so we benchmark the jitted oracle
-paths (what the CPU backend actually executes) and report the kernel's
-analytic VMEM working set per grid step, which is the quantity the
-BlockSpecs were chosen against (v5e: ~128MB VMEM/core)."""
+Times the jitted pure-jnp oracle paths on the default backend and reports
+each kernel's analytic VMEM working set per grid step, the quantity the
+BlockSpecs were chosen against (v5e: ~128MB VMEM/core).  Off TPU the
+Pallas kernels run only in the interpreter, whose wall time says nothing
+about the chip, so they are not timed here."""
 from __future__ import annotations
 
 import time
@@ -30,7 +30,8 @@ def main():
     from repro.kernels.selective_scan.ref import selective_scan_ref
     from repro.kernels.simstep.ref import simstep_ref
 
-    print("# kernel oracle paths (CPU) + VMEM working sets (TPU design)")
+    print(f"# kernel oracle paths ({jax.default_backend()}) + VMEM working "
+          "sets (TPU design)")
     print("name,us_per_call,derived")
 
     # simstep: 4096 VMs x 64 slots

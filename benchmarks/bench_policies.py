@@ -8,10 +8,11 @@ vmapped XLA call (policies x scenarios flattened into a single lane
 axis) vs a sequential loop of single runs.
 
 ``bench_sharded`` measures the device-sharded path: the fused grid split
-across a forced multi-device host platform
-(``--xla_force_host_platform_device_count``) vs the same grid on one
-device.  It re-launches itself in a subprocess because the device count
-is fixed at backend initialization.
+across every visible device vs the same grid on one device.  On an
+accelerator it runs on the real devices; on CPU ``main`` runs it in a
+child of its own with a forced host-platform device count
+(``--xla_force_host_platform_device_count``), which is fixed at backend
+initialization.
 
 ``bench_migration`` measures the dynamic-event subsystem's overhead: the
 same workload compiled as the static program (``dynamic=False``), as the
@@ -43,7 +44,12 @@ proof of the static-gate promise.
 Besides the CSV-ish stdout lines, ``main`` writes every measurement to
 ``BENCH_policies.json`` at the repo root so the perf trajectory is
 recorded run-over-run (cells/s for single vs gspmd vs shard_map, energy
-accounting overhead, migration overhead)."""
+accounting overhead, migration overhead), with the platform, device kind
+and device count in its ``meta``.
+
+A device belongs to one process at a time, so ``main`` never touches
+JAX: every phase runs in a child of this file, one after another, and a
+child that fails makes ``main`` exit non-zero without writing the JSON."""
 from __future__ import annotations
 
 import json
@@ -583,18 +589,12 @@ def bench_streaming(tiers=(10_000, 100_000, 1_000_000), window=64,
     for n in tiers:
         tier = {}
         for mode in ("streamed", "resident"):
-            proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__),
-                 "--streaming-worker", str(n), mode, str(window),
-                 str(chunk)],
-                capture_output=True, text=True, timeout=1800)
-            if proc.returncode != 0:
-                tier[mode] = {"error": f"rc={proc.returncode}"}
-                sys.stderr.write(proc.stderr[-2000:])
-                continue
-            for line in proc.stdout.splitlines():
-                if line.startswith("STREAM_WORKER_JSON:"):
-                    tier[mode] = json.loads(line.split(":", 1)[1])
+            try:
+                tier[mode] = _worker(
+                    ["--streaming-worker", str(n), mode, str(window),
+                     str(chunk)], "STREAM_WORKER_JSON:", timeout=1800)
+            except WorkerFailed as e:
+                tier[mode] = {"error": str(e)}
         sm = tier.get("streamed", {})
         if sm.get("wall_s"):
             sm["cloudlets_per_s"] = n / sm["wall_s"]
@@ -778,8 +778,7 @@ def bench_sharded(batch=16, n_hosts=256, n_vms=32, max_steps=8192):
     }
 
 
-def _sharded_worker():
-    sh = bench_sharded()
+def _print_sharded(sh):
     print(f"policy_sweep_sharded,{sh['dispatch_s']*1e6:.0f},"
           f"devices={sh['devices']}_cells={sh['cells']}"
           f"_single_dev={sh['single_cells_per_s']:.1f}cells_per_s"
@@ -787,10 +786,22 @@ def _sharded_worker():
           f"_shard_map={sh['shard_map_cells_per_s']:.1f}cells_per_s"
           f"_dispatch={sh['dispatch_cells_per_s']:.1f}cells_per_s"
           f"_best_speedup={sh['speedup']:.2f}x")
+
+
+def _sharded_worker():
+    sh = bench_sharded()
+    _print_sharded(sh)
     print("BENCH_SHARDED_JSON:" + json.dumps(sh))
 
 
-def main():
+def _device_phases():
+    """Child that owns the device: every in-process bench, one process.
+
+    On an accelerator the sharded bench runs here on the real devices;
+    on CPU the parent runs it afterwards in a forced-host-device child.
+    """
+    import jax
+
     results = {}
     print("# Fig 8/9: space vs time shared tasks (10k hosts, 50 VMs, "
           "500 cloudlets)")
@@ -842,19 +853,6 @@ def main():
           f"_ups={bel['autoscaled']['ups']}"
           f"_downs={bel['autoscaled']['downs']}"
           f"_spot=${bel['autoscaled']['spot_cost']:.2f}")
-    bs = bench_streaming()
-    results["streaming"] = bs
-    for n, tier in bs.items():
-        sm, rs = tier.get("streamed", {}), tier.get("resident", {})
-        wall, rwall = sm.get("wall_s"), rs.get("wall_s")
-        us = f"{wall * 1e6:.0f}" if wall else "error"
-        rw = f"{rwall:.1f}s" if rwall else "not_timed"
-        print(f"bench_streaming_{n},{us},"
-              f"cloudlets_per_s={sm.get('cloudlets_per_s', 0):.0f}"
-              f"_retired={sm.get('retired')}"
-              f"_rss={sm.get('peak_rss_mb', 0):.0f}MB"
-              f"_resident_rss={rs.get('peak_rss_mb', 0):.0f}MB"
-              f"_resident_wall={rw}")
     bmx = bench_metrics()
     results["bench_metrics"] = bmx
     msw = bmx["sweep"]
@@ -865,36 +863,96 @@ def main():
           f"_stream_probed_overhead="
           f"{bmx['streaming']['probed_overhead']:.2f}x"
           f"_retired={msw['retired']}")
-    # the sharded measurement needs a multi-device backend, which must be
-    # forced before jax initializes -> fresh subprocess
-    env = dict(
-        os.environ,
-        XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                   + " --xla_force_host_platform_device_count=2").strip())
+    if jax.default_backend() != "cpu":
+        results["sharded"] = bench_sharded()
+        _print_sharded(results["sharded"])
+    dev = jax.devices()[0]
+    results["meta"] = {"platform": dev.platform,
+                       "device_kind": dev.device_kind,
+                       "device_count": jax.device_count()}
+    print("BENCH_PHASES_JSON:" + json.dumps(results))
+
+
+class WorkerFailed(RuntimeError):
+    pass
+
+
+def _worker(args, marker, *, env=None, timeout):
+    """Run this file as a child with ``args``; relay its stdout and return
+    the JSON it prints after ``marker``.  Raises ``WorkerFailed`` when the
+    child fails, times out or prints no result."""
     try:
         proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--sharded-worker"],
-            env=env, capture_output=True, text=True, timeout=900)
+            [sys.executable, os.path.abspath(__file__), *args], env=env,
+            capture_output=True, text=True, timeout=timeout)
     except subprocess.TimeoutExpired:
-        print("policy_sweep_sharded,error,worker_timeout_900s")
-        proc = None
-    if proc is not None and proc.returncode == 0:
-        for line in proc.stdout.splitlines():
-            if line.startswith("BENCH_SHARDED_JSON:"):
-                results["sharded"] = json.loads(
-                    line.split(":", 1)[1])
-            else:
-                print(line)
-    elif proc is not None:
-        print(f"policy_sweep_sharded,error,"
-              f"worker_failed_rc={proc.returncode}")
+        raise WorkerFailed(f"{args[0]} timed out after {timeout}s")
+    found = None
+    for line in proc.stdout.splitlines():
+        if line.startswith(marker):
+            found = json.loads(line[len(marker):])
+        else:
+            print(line)
+    if proc.returncode != 0 or found is None:
         sys.stderr.write(proc.stderr[-2000:])
+        raise WorkerFailed(f"{args[0]} rc={proc.returncode}")
+    return found
+
+
+def main() -> int:
+    """Run every bench and record them; non-zero when any phase failed.
+
+    This process never touches JAX: a device belongs to one process at a
+    time, so each phase that needs it runs in a child, one after another.
+    """
+    failed = []
+    try:
+        results = _worker(["--device-phases"], "BENCH_PHASES_JSON:",
+                          timeout=3600)
+    except WorkerFailed as e:
+        print(f"# device phases failed: {e}")
+        failed.append("device_phases")
+        results = {"meta": {}}
+    bs = bench_streaming()
+    results["streaming"] = bs
+    for n, tier in bs.items():
+        sm, rs = tier.get("streamed", {}), tier.get("resident", {})
+        failed += [f"streaming_{n}_{mode}" for mode in ("streamed",
+                                                        "resident")
+                   if "error" in tier[mode]]
+        wall, rwall = sm.get("wall_s"), rs.get("wall_s")
+        us = f"{wall * 1e6:.0f}" if wall else "error"
+        rw = f"{rwall:.1f}s" if rwall else "not_timed"
+        print(f"bench_streaming_{n},{us},"
+              f"cloudlets_per_s={sm.get('cloudlets_per_s', 0):.0f}"
+              f"_retired={sm.get('retired')}"
+              f"_rss={sm.get('peak_rss_mb', 0):.0f}MB"
+              f"_resident_rss={rs.get('peak_rss_mb', 0):.0f}MB"
+              f"_resident_wall={rw}")
+    if results["meta"].get("platform") == "cpu":
+        # the CPU backend exposes one device unless a host device count
+        # is forced before JAX initializes -> a child of its own
+        env = dict(
+            os.environ,
+            XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                       + " --xla_force_host_platform_device_count=2").strip())
+        try:
+            results["sharded"] = _worker(
+                ["--sharded-worker"], "BENCH_SHARDED_JSON:", env=env,
+                timeout=900)
+        except WorkerFailed as e:
+            print(f"policy_sweep_sharded,error,{e}")
+            failed.append("sharded")
+    if failed:
+        print(f"# FAILED phases: {failed}; BENCH_policies.json not written")
+        return 1
     _write_json(results)
+    return 0
 
 
 def _write_json(results):
     """Record the run in BENCH_policies.json (the perf trajectory file)."""
-    results["meta"] = {"python": sys.version.split()[0]}
+    results["meta"]["python"] = sys.version.split()[0]
     path = os.path.abspath(_JSON_PATH)
     with open(path, "w") as f:
         json.dump(results, f, indent=1, sort_keys=True)
@@ -903,11 +961,17 @@ def _write_json(results):
 
 
 if __name__ == "__main__":
-    if "--sharded-worker" in sys.argv:
+    if len(sys.argv) == 1:
+        sys.exit(main())
+    from repro import compat        # a child: it owns the device
+
+    compat.use_compile_cache()
+    if sys.argv[1] == "--device-phases":
+        _device_phases()
+    elif sys.argv[1] == "--sharded-worker":
         _sharded_worker()
-    elif "--streaming-worker" in sys.argv:
-        i = sys.argv.index("--streaming-worker")
-        _streaming_worker(int(sys.argv[i + 1]), sys.argv[i + 2],
-                          int(sys.argv[i + 3]), int(sys.argv[i + 4]))
+    elif sys.argv[1] == "--streaming-worker":
+        _streaming_worker(int(sys.argv[2]), sys.argv[3], int(sys.argv[4]),
+                          int(sys.argv[5]))
     else:
-        main()
+        sys.exit(f"unknown argument {sys.argv[1]!r}")

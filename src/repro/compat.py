@@ -1,55 +1,42 @@
-"""Compatibility shims for the pinned container toolchain.
-
-The code targets the modern JAX surface (``jax.shard_map`` with the
-``check_vma`` kwarg, ``jax.make_mesh``); the container pins jax 0.4.x
-where shard_map lives in ``jax.experimental.shard_map`` with a
-``check_rep`` kwarg and ``make_mesh`` may be absent.  One shim keeps
-every call site on the modern spelling.
-"""
+"""Toolchain setup shared by every entry point: sweep meshes and the
+persistent compilation cache."""
 from __future__ import annotations
+
+import os
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
-try:
-    _shard_map = jax.shard_map          # jax >= 0.5
-    _CHECK_KW = "check_vma"
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
+__all__ = ["make_mesh", "use_compile_cache"]
 
-__all__ = ["shard_map", "make_mesh", "abstract_mesh"]
-
-
-def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` with the modern (sizes, names) call.
-
-    jax 0.4.x spells the constructor ``AbstractMesh(shape_tuple)`` with
-    zipped (name, size) pairs; 0.5+ takes the two sequences.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+# Fixed, inside the checkout: the cache directory is part of each entry's
+# key, so a path that moved between runs (temp name, pid, time) never hits.
+_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    ".jax_cache"))
 
 
 def make_mesh(axis: str = "sweep", devices=None) -> Mesh:
     """A 1-D device mesh named ``axis`` (default: all local devices).
 
-    ``jax.make_mesh`` only landed late in 0.4.x; ``jax.sharding.Mesh``
-    over an explicit device array works everywhere, so use that.
+    ``jax.make_mesh`` builds Explicit-typed axes, under which the sweep
+    runners' ``with_sharding_constraint``/``shard_map`` spellings change
+    meaning; a plain ``Mesh`` over the device array keeps Auto axes.
     """
     devices = jax.devices() if devices is None else list(devices)
     return Mesh(np.asarray(devices), (axis,))
 
 
-def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` with the modern signature on any supported jax."""
-    kwargs = {} if check_vma is None else {_CHECK_KW: check_vma}
-    if f is None:
-        return lambda g: _shard_map(g, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs, **kwargs)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it
+    and nothing is changed.  Otherwise the cache goes to ``.jax_cache``
+    at the root of the checkout.  Call before the first compilation.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    return _CACHE_DIR

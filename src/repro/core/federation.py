@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import broker, cis
 from repro.core import state as S
 from repro.core.engine import run
@@ -144,7 +143,7 @@ def federated_run(mesh: Mesh, dc_stack: S.DatacenterState, *,
     spec = P(axis)
 
     @partial(
-        compat.shard_map, mesh=mesh, in_specs=(spec,),
+        jax.shard_map, mesh=mesh, in_specs=(spec,),
         out_specs=(spec, spec, P()), check_vma=False)
     def go(dc_block):
         dc = jax.tree.map(lambda x: x[0], dc_block)

@@ -15,7 +15,7 @@ scenario ``b`` under policy pair ``p``), reshaping results back to
 the whole grid is still a single compilation.
 
 The fused lane axis is also the *sharding* axis: ``run_sharded`` splits
-it across the devices of a 1-D mesh — with ``compat.shard_map``, or
+it across the devices of a 1-D mesh — with ``jax.shard_map``, or
 with GSPMD lane-axis ``in_shardings`` on the CPU backend (see
 ``run_sharded``) — lanes are fully independent (no collectives), so
 sweep throughput scales linearly in devices.  Lane counts that do not
@@ -447,15 +447,15 @@ def _sharded_runner(mesh, axis: str, max_steps: int, provision_policy: int,
 
     ``inner`` picks how a device iterates its lane block: ``"vmap"``
     batches the block into wide ops, ``"map"`` runs lanes back-to-back
-    with ``lax.map``.  The pinned jaxlib's *CPU* SPMD partitioner
-    hard-crashes (``TileAssignment::Reshape`` check failure) on a vmapped
+    with ``lax.map``.  jaxlib 0.4.37's *CPU* SPMD partitioner
+    hard-crashed (``TileAssignment::Reshape`` check failure) on a vmapped
     engine step inside ``shard_map``, so CPU defaults to ``"map"``; both
     spellings are bit-for-bit equal per lane.
     """
     spec = P(axis)
 
     @jax.jit
-    @partial(compat.shard_map, mesh=mesh, in_specs=(spec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
              out_specs=spec, check_vma=False)
     def go(block: DatacenterState) -> DatacenterState:
         f = partial(engine.run, max_steps=max_steps,
@@ -507,9 +507,9 @@ def run_sharded(batch: DatacenterState, *, mesh=None, axis: str = "sweep",
 
     ``partitioner`` selects how lanes land on devices:
 
-    * ``"shard_map"`` — explicit ``compat.shard_map`` over ``axis``; each
+    * ``"shard_map"`` — explicit ``jax.shard_map`` over ``axis``; each
       device iterates its block per ``inner`` ("vmap" | "map", default
-      "map" on CPU where the pinned jaxlib cannot compile the vmapped
+      "map" on CPU, where jaxlib 0.4.37 could not compile the vmapped
       engine under manual sharding, "vmap" elsewhere).
     * ``"gspmd"`` — ``jit`` with lane-axis ``in_shardings``; XLA's
       automatic partitioner splits the ordinary ``run_batch`` program,
@@ -601,7 +601,7 @@ def _grid_runner(mesh, max_steps: int, provision_policy: int,
             else:
                 body = jax.vmap(run_lane) if inner == "vmap" \
                     else partial(jax.lax.map, run_lane)
-                out = compat.shard_map(
+                out = jax.shard_map(
                     body, mesh=mesh, in_specs=(P(axis),),
                     out_specs=P(axis), check_vma=False)(padded)
             out = jax.tree_util.tree_map(
@@ -845,8 +845,8 @@ def _stream_batch_runner(provision_policy: int, dynamic: bool,
     """jit(vmap(engine._stream_core)) for one static config.
 
     ``mesh`` adds GSPMD lane-axis in/out shardings (the only sharded
-    spelling offered for streams: the pinned jaxlib's CPU manual-sharding
-    partitioner cannot compile a vmapped engine step under ``shard_map``
+    spelling offered for streams: jaxlib 0.4.37's CPU manual-sharding
+    partitioner could not compile a vmapped engine step under ``shard_map``
     — ROADMAP landmine #1 — and GSPMD keeps the wide-vmap program
     identical on every backend)."""
     f = partial(engine._stream_core, provision_policy=provision_policy,

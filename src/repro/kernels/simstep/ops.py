@@ -1,6 +1,6 @@
-"""Dispatching wrapper for the simstep kernel: Pallas on TPU, pure-jnp
-oracle elsewhere (this container is CPU-only; interpret=True exercises the
-kernel body in tests)."""
+"""Dispatching wrapper for the simstep kernel: the compiled Pallas kernel
+on TPU, the pure-jnp oracle on other backends (tests drive the kernel body
+there with ``interpret=True``)."""
 from __future__ import annotations
 
 import jax

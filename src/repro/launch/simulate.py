@@ -33,11 +33,13 @@ def main():
                     help="emit a completion curve with N trace steps")
     args = ap.parse_args()
 
+    from repro import compat
     from repro.core import broker as B
     from repro.core import state as S
     from repro.core.engine import run, run_trace
     from repro.core.telemetry import completion_curve, summarize_trace
 
+    compat.use_compile_cache()
     pol = {"space": S.SPACE_SHARED, "time": S.TIME_SHARED}
 
     if args.lm_profile:

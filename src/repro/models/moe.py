@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.segments import segment_rank
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, swiglu
@@ -156,7 +155,7 @@ def moe_block_ep(params: dict, cfg: ModelConfig, x: jnp.ndarray,
         "down": P(model_axis, None, fsdp if fsdp else None),
     }
 
-    @partial(compat.shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(tok_spec, w_specs),
              out_specs=(tok_spec, P()), check_vma=False)
     def ep(xf, w):

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 
 __all__ = ["quantize_int8", "dequantize_int8", "ef_compress_tree",
            "allreduce_compressed"]
@@ -78,7 +77,7 @@ def allreduce_compressed(mesh: Mesh, axis: str, tree):
     nshards = mesh.shape[axis]
 
     def one(x):
-        @partial(compat.shard_map, mesh=mesh, in_specs=P(axis),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(axis),
                  out_specs=P(), check_vma=False)
         def go(block):
             local = block[0]                     # this pod's gradient

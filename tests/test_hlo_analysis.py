@@ -94,8 +94,6 @@ f = jax.jit(lambda a, b: a @ b,
                           NamedSharding(mesh, P())),
             out_shardings=NamedSharding(mesh, P('x', None)))
 ca = f.lower(A, B).compile().cost_analysis()
-if isinstance(ca, (list, tuple)):   # jax <= 0.4.x: one dict per program
-    ca = ca[0]
 total = 2 * 1024 * 512 * 256
 assert abs(ca['flops'] - total / 2) / total < 0.01, ca['flops']
 print('OK')

@@ -2,9 +2,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro import compat
 from repro import configs as CFG
 from repro.data.synthetic import SyntheticConfig, config_for, make_batch
 from repro.launch import specs as SP
@@ -41,7 +40,7 @@ def test_param_specs_no_duplicate_axes():
 def test_divisibility_fallback_replicates():
     """Indivisible dims must fall back to replication (abstract 16x16
     production mesh — rule logic only needs mesh.shape)."""
-    mesh = compat.abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     rules = ShardingRules()
     cfg = CFG.get_config("llava-next-34b")       # 56 q heads x 128
     pshapes = SP.params_shapes(cfg)
