@@ -75,7 +75,7 @@ from repro.core.state import (
 )
 
 __all__ = ["step", "run", "run_trace", "batched_run", "run_stream",
-           "StepRecord", "StreamChunkRecord", "apply_due_events",
+           "StepRecord", "RunStats", "StreamChunkRecord", "apply_due_events",
            "apply_autoscaler", "wants_dynamic", "wants_network",
            "wants_elastic", "wants_probes"]
 
@@ -86,6 +86,10 @@ _EPS_MI = 1e-3      # absolute snap threshold, in million instructions
 # one record per event.  Tests force both settings and assert bitwise
 # equality (tests/test_leap_parity.py).
 _LEAP_DEFAULT = True
+
+# Host spans on the profiler's clock around the public runners' host work;
+# without a running profiler each costs well under a microsecond.
+_span = jax.profiler.TraceAnnotation
 
 
 class StepRecord(NamedTuple):
@@ -105,6 +109,13 @@ class StepRecord(NamedTuple):
     #                                  > 1 when the horizon leap fired)
     fleet: jnp.ndarray         # i32[] alive (PENDING|ACTIVE) VMs *after* step
     spot_cost: jnp.ndarray     # f32[] cumulative spot spend *after* the step
+
+
+class RunStats(NamedTuple):
+    """Loop counters the while_loop runners return beside the final state
+    (scalar for ``run``, one per lane for ``batched_run``)."""
+    iterations: jnp.ndarray    # i32 loop trips while the lane was live
+    events: jnp.ndarray        # i32 events retired, the leap's included
 
 
 def _hit(n: int, idx: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
@@ -692,237 +703,255 @@ def step(dc: DatacenterState, *, provision_policy=FIRST_FIT,
     # selects — both branches run — so batched callers lose nothing; the
     # unbatched while_loop runners (and lax.map inner loops) get real
     # branches.
+    # Each pass runs under a ``jax.named_scope`` (events, autoscaler,
+    # provision, phases, rates, migration, flows, horizon, commit, probes,
+    # leap, record): op metadata only, so a device trace can sum time per
+    # pass (docs/observability.md).
     if dynamic and dc.events.shape[0]:
-        ev_k = dc.events[:, 1].astype(jnp.int32)
-        due_any = jnp.any((~dc.event_fired) & (ev_k != EV_NONE)
-                          & (dc.events[:, 0] <= dc.time))
-        dc = jax.lax.cond(due_any, apply_due_events, lambda d: d, dc)
+        with jax.named_scope("events"):
+            ev_k = dc.events[:, 1].astype(jnp.int32)
+            due_any = jnp.any((~dc.event_fired) & (ev_k != EV_NONE)
+                              & (dc.events[:, 0] <= dc.time))
+            dc = jax.lax.cond(due_any, apply_due_events, lambda d: d, dc)
     if elastic:
-        dc = jax.lax.cond(dc.scaler.enabled == 1, apply_autoscaler,
+        with jax.named_scope("autoscaler"):
+            dc = jax.lax.cond(dc.scaler.enabled == 1, apply_autoscaler,
+                              lambda d: d, dc)
+    with jax.named_scope("provision"):
+        pending_due = jnp.any((dc.vms.state == VM_PENDING)
+                              & (dc.vms.submit_time <= dc.time))
+        dc = jax.lax.cond(pending_due,
+                          lambda d: provision_pending(d, provision_policy),
                           lambda d: d, dc)
-    pending_due = jnp.any((dc.vms.state == VM_PENDING)
-                          & (dc.vms.submit_time <= dc.time))
-    dc = jax.lax.cond(pending_due,
-                      lambda d: provision_pending(d, provision_policy),
-                      lambda d: d, dc)
     if networked:
-        dc = jax.lax.cond(dc.net.enabled == 1, network.advance_phases,
-                          lambda d: d, dc)
-    rates = scheduling.cloudlet_rates(dc, networked=networked,
-                                      streaming=streaming)
+        with jax.named_scope("phases"):
+            dc = jax.lax.cond(dc.net.enabled == 1, network.advance_phases,
+                              lambda d: d, dc)
+    with jax.named_scope("rates"):
+        rates = scheduling.cloudlet_rates(dc, networked=networked,
+                                          streaming=streaming)
     if dynamic:
-        mig0 = migration.select_migration(dc, rates, networked=networked)
+        with jax.named_scope("migration"):
+            mig0 = migration.select_migration(dc, rates, networked=networked)
 
-        def _mig_apply(op):
-            d, r = op
-            d2 = migration.apply_selected(d, mig0)
-            r2 = scheduling.cloudlet_rates(d2, networked=networked,
-                                           streaming=streaming)
-            t2 = migration.select_migration(
-                d2, r2, networked=networked).trigger
-            return d2, r2, t2
+            def _mig_apply(op):
+                d, r = op
+                d2 = migration.apply_selected(d, mig0)
+                r2 = scheduling.cloudlet_rates(d2, networked=networked,
+                                               streaming=streaming)
+                t2 = migration.select_migration(
+                    d2, r2, networked=networked).trigger
+                return d2, r2, t2
 
-        def _mig_skip(op):
-            # no-trigger apply is an identity and re-derives identical
-            # rates/trigger, so the skip branch is bitwise equivalent
-            d, r = op
-            return d, r, jnp.bool_(False)
+            def _mig_skip(op):
+                # no-trigger apply is an identity and re-derives identical
+                # rates/trigger, so the skip branch is bitwise equivalent
+                d, r = op
+                return d, r, jnp.bool_(False)
 
-        dc, rates, trig_next = jax.lax.cond(mig0.trigger, _mig_apply,
-                                            _mig_skip, (dc, rates))
+            dc, rates, trig_next = jax.lax.cond(mig0.trigger, _mig_apply,
+                                                _mig_skip, (dc, rates))
     if networked:
-        def _net_on(d):
-            fr = network.flow_rates(d)
-            dtn, fdt = network.wake_deltas(d, fr)
-            return fr, dtn, fdt
+        with jax.named_scope("flows"):
+            def _net_on(d):
+                fr = network.flow_rates(d)
+                dtn, fdt = network.wake_deltas(d, fr)
+                return fr, dtn, fdt
 
-        def _net_off(d):
-            # flow_rates/wake_deltas of a disabled topology, verbatim
-            nc = d.cloudlets.remaining.shape[0]
-            return (jnp.zeros((nc,), jnp.float32), jnp.float32(INF),
-                    jnp.full((nc,), INF, jnp.float32))
+            def _net_off(d):
+                # flow_rates/wake_deltas of a disabled topology, verbatim
+                nc = d.cloudlets.remaining.shape[0]
+                return (jnp.zeros((nc,), jnp.float32), jnp.float32(INF),
+                        jnp.full((nc,), INF, jnp.float32))
 
-        frates, dt_net, flow_dt = jax.lax.cond(dc.net.enabled == 1,
-                                               _net_on, _net_off, dc)
+            frates, dt_net, flow_dt = jax.lax.cond(dc.net.enabled == 1,
+                                                   _net_on, _net_off, dc)
 
-    dt_other, finish_dt, arrive = _next_event_deltas(dc, rates)
-    if dynamic:
-        dt_dyn, arr_ev = _dynamic_deltas(dc, trig_next)
-        dt_other = jnp.minimum(dt_other, dt_dyn)
-        arrive = jnp.minimum(arrive, arr_ev)
-    if networked:
-        dt_other = jnp.minimum(dt_other, dt_net)
-    if streaming:
-        # pending stream arrival — absolute, exact; a backlogged one
-        # (submit <= now, window full) is no event: a completion frees a
-        # slot first and the driver's admission pass picks it up
-        arrive = jnp.minimum(arrive, jnp.where(next_arrival > dc.time,
-                                               next_arrival, INF))
-    if elastic:
-        # spot-segment boundaries are absolute arrivals (exact f32 table
-        # values), so the piecewise-constant accrual below is exact;
-        # INF while the track is disabled, leaving ``arrive`` untouched
-        arrive = jnp.minimum(arrive,
-                             market.next_spot_boundary(dc.scaler, dc.time))
-    dt_arr = jnp.where(arrive < INF, arrive - dc.time, INF)
-    dt = jnp.minimum(dt_other, dt_arr)
-    active = dt < INF
-    dt = jnp.where(active, dt, 0.0)
-    # arrivals win ties so the clock lands on the exact submitted time
-    t_next = jnp.where(active,
-                       jnp.where(dt_arr <= dt_other, arrive, dc.time + dt),
-                       dc.time)
+    with jax.named_scope("horizon"):
+        dt_other, finish_dt, arrive = _next_event_deltas(dc, rates)
+        if dynamic:
+            dt_dyn, arr_ev = _dynamic_deltas(dc, trig_next)
+            dt_other = jnp.minimum(dt_other, dt_dyn)
+            arrive = jnp.minimum(arrive, arr_ev)
+        if networked:
+            dt_other = jnp.minimum(dt_other, dt_net)
+        if streaming:
+            # pending stream arrival — absolute, exact; a backlogged one
+            # (submit <= now, window full) is no event: a completion frees a
+            # slot first and run_stream's admission pass picks it up
+            arrive = jnp.minimum(arrive, jnp.where(next_arrival > dc.time,
+                                                   next_arrival, INF))
+        if elastic:
+            # spot-segment boundaries are absolute arrivals (exact f32 table
+            # values), so the piecewise-constant accrual below is exact;
+            # INF while the track is disabled, leaving ``arrive`` untouched
+            arrive = jnp.minimum(arrive,
+                                 market.next_spot_boundary(dc.scaler, dc.time))
+        dt_arr = jnp.where(arrive < INF, arrive - dc.time, INF)
+        dt = jnp.minimum(dt_other, dt_arr)
+        active = dt < INF
+        dt = jnp.where(active, dt, 0.0)
+        # arrivals win ties so the clock lands on the exact submitted time
+        t_next = jnp.where(active,
+                           jnp.where(dt_arr <= dt_other, arrive, dc.time + dt),
+                           dc.time)
 
-    cl = dc.cloudlets
-    executed = rates * dt
-    # completion snap band, shared by every countdown in this commit and
-    # mirrored by the oracle's _SNAP_REL/_SNAP_ABS — keep in sync
-    snap = dt * (1.0 + 1e-5) + 1e-9
-    # the argmin task(s) finish *by construction* — immune to f32 rounding
-    finished = ((cl.state == CL_CREATED)
-                & (rates > 0.0)
-                & (finish_dt <= snap))
-    remaining = jnp.where(finished, 0.0,
-                          jnp.maximum(cl.remaining - executed, 0.0))
+    with jax.named_scope("commit"):
+        cl = dc.cloudlets
+        executed = rates * dt
+        # completion snap band, shared by every countdown in this commit and
+        # mirrored by the oracle's _SNAP_REL/_SNAP_ABS — keep in sync
+        snap = dt * (1.0 + 1e-5) + 1e-9
+        # the argmin task(s) finish *by construction* — immune to f32 rounding
+        finished = ((cl.state == CL_CREATED)
+                    & (rates > 0.0)
+                    & (finish_dt <= snap))
+        remaining = jnp.where(finished, 0.0,
+                              jnp.maximum(cl.remaining - executed, 0.0))
 
-    started = (rates > 0.0) & (cl.start_time < 0.0)
-    start_time = jnp.where(started, dc.time, cl.start_time)
-    net_phase, net_lat, net_rem = cl.net_phase, cl.net_lat, cl.net_remaining
-    if networked:
-        # enabled lanes: compute completion arms the output transfer
-        # instead of finishing (NET_STAGE_OUT; ``advance_phases`` marks
-        # CL_DONE once it drains); disabled lanes keep old semantics.
-        enabled = dc.net.enabled == 1
-        done_now = finished & ~enabled
-        arm_out = finished & enabled
-        # transfer countdowns — the same snap band as completions, so
-        # the wake event lands on the same step as the f64 oracle's
-        lat_active = network.staging_mask(dc) & (cl.net_lat > 0.0)
-        lat_done = lat_active & (cl.net_lat <= snap)
-        net_lat = jnp.where(
-            lat_done, 0.0,
-            jnp.where(lat_active, jnp.maximum(cl.net_lat - dt, 0.0),
-                      cl.net_lat))
-        xfer_done = (frates > 0.0) & (flow_dt <= snap)
-        net_rem = jnp.where(
-            xfer_done, 0.0,
-            jnp.where(frates > 0.0,
-                      jnp.maximum(cl.net_remaining - frates * dt, 0.0),
-                      cl.net_remaining))
-        # a compute-finished cloudlet is in NET_RUN — never also a flow —
-        # so arming cannot clash with the countdowns above
-        net_phase = jnp.where(arm_out, NET_STAGE_OUT, cl.net_phase)
-        net_lat = jnp.where(arm_out, network.stage_latency(dc), net_lat)
-        net_rem = jnp.where(arm_out, cl.output_size, net_rem)
-    else:
-        done_now = finished
-    finish_time = jnp.where(done_now, t_next, cl.finish_time)
-    state = jnp.where(done_now, CL_DONE, cl.state)
+        started = (rates > 0.0) & (cl.start_time < 0.0)
+        start_time = jnp.where(started, dc.time, cl.start_time)
+        net_phase, net_lat = cl.net_phase, cl.net_lat
+        net_rem = cl.net_remaining
+        if networked:
+            # enabled lanes: compute completion arms the output transfer
+            # instead of finishing (NET_STAGE_OUT; ``advance_phases`` marks
+            # CL_DONE once it drains); disabled lanes keep old semantics.
+            enabled = dc.net.enabled == 1
+            done_now = finished & ~enabled
+            arm_out = finished & enabled
+            # transfer countdowns — the same snap band as completions, so
+            # the wake event lands on the same step as the f64 oracle's
+            lat_active = network.staging_mask(dc) & (cl.net_lat > 0.0)
+            lat_done = lat_active & (cl.net_lat <= snap)
+            net_lat = jnp.where(
+                lat_done, 0.0,
+                jnp.where(lat_active, jnp.maximum(cl.net_lat - dt, 0.0),
+                          cl.net_lat))
+            xfer_done = (frates > 0.0) & (flow_dt <= snap)
+            net_rem = jnp.where(
+                xfer_done, 0.0,
+                jnp.where(frates > 0.0,
+                          jnp.maximum(cl.net_remaining - frates * dt, 0.0),
+                          cl.net_remaining))
+            # a compute-finished cloudlet is in NET_RUN — never also a flow —
+            # so arming cannot clash with the countdowns above
+            net_phase = jnp.where(arm_out, NET_STAGE_OUT, cl.net_phase)
+            net_lat = jnp.where(arm_out, network.stage_latency(dc), net_lat)
+            net_rem = jnp.where(arm_out, cl.output_size, net_rem)
+        else:
+            done_now = finished
+        finish_time = jnp.where(done_now, t_next, cl.finish_time)
+        state = jnp.where(done_now, CL_DONE, cl.state)
 
-    # ---- market accounting (§3.3) ----------------------------------------
-    nv = dc.vms.req_pes.shape[0]
-    nh = dc.hosts.num_pes.shape[0]
-    host_of_cl = dc.vms.host[jnp.clip(cl.vm, 0, nv - 1)]
-    mips_pe = dc.hosts.mips_per_pe[jnp.clip(host_of_cl, 0, nh - 1)]
-    pe_seconds = jnp.sum(executed / jnp.maximum(mips_pe, 1e-30))
-    cpu_cost = dc.acct.cpu_cost + dc.rates.cost_per_cpu_sec * pe_seconds
-    # networked lanes bill per drained transfer below
-    # (``transfer_accounting``; ``done_now`` excludes them) — same total
-    # per finished task
-    moved_mb = jnp.sum(jnp.where(done_now, cl.file_size + cl.output_size,
-                                 0.0))
-    bw_cost = dc.acct.bw_cost + dc.rates.cost_per_bw * moved_mb
+        # ---- market accounting (§3.3) ------------------------------------
+        nv = dc.vms.req_pes.shape[0]
+        nh = dc.hosts.num_pes.shape[0]
+        host_of_cl = dc.vms.host[jnp.clip(cl.vm, 0, nv - 1)]
+        mips_pe = dc.hosts.mips_per_pe[jnp.clip(host_of_cl, 0, nh - 1)]
+        pe_seconds = jnp.sum(executed / jnp.maximum(mips_pe, 1e-30))
+        cpu_cost = dc.acct.cpu_cost + dc.rates.cost_per_cpu_sec * pe_seconds
+        # networked lanes bill per drained transfer below
+        # (``transfer_accounting``; ``done_now`` excludes them) — same total
+        # per finished task
+        moved_mb = jnp.sum(jnp.where(done_now, cl.file_size + cl.output_size,
+                                     0.0))
+        bw_cost = dc.acct.bw_cost + dc.rates.cost_per_bw * moved_mb
 
-    # ---- energy accounting (core/energy.py) ------------------------------
-    # Rates are constant on [time, time+dt), so power is too: the exact
-    # integral of the piecewise-constant power timeline is watts * dt per
-    # event (the trapezoidal rule with equal endpoints).  At quiescence
-    # dt == 0, so energy_j is a bit-exact fixed point like everything else.
-    host_watts = energy.step_power(dc, rates)              # f32[H]
-    energy_j = dc.hosts.energy_j + host_watts * dt
+        # ---- energy accounting (core/energy.py) --------------------------
+        # Rates are constant on [time, time+dt), so power is too: the exact
+        # integral of the piecewise-constant power timeline is watts * dt per
+        # event (the trapezoidal rule with equal endpoints).  At quiescence
+        # dt == 0, so energy_j is a bit-exact fixed point like everything else.
+        host_watts = energy.step_power(dc, rates)              # f32[H]
+        energy_j = dc.hosts.energy_j + host_watts * dt
 
-    transferred_mb = dc.net_transferred_mb
-    if networked:
-        # drained transfers book their whole size on this (active) step
-        xfer_energy, moved = network.transfer_accounting(dc, xfer_done)
-        energy_j = energy_j + xfer_energy
-        bw_cost = bw_cost + dc.rates.cost_per_bw * moved
-        transferred_mb = transferred_mb + moved
+        transferred_mb = dc.net_transferred_mb
+        if networked:
+            # drained transfers book their whole size on this (active) step
+            xfer_energy, moved = network.transfer_accounting(dc, xfer_done)
+            energy_j = energy_j + xfer_energy
+            bw_cost = bw_cost + dc.rates.cost_per_bw * moved
+            transferred_mb = transferred_mb + moved
 
-    vms = dc.vms
-    if dynamic:
-        # migration copy countdown — a delta like cloudlet ``remaining``,
-        # with the same completion snap band so the resume event lands on
-        # the same step on both the engine and the f64 oracle.
-        mig = vms.mig_remaining
-        mig_done = (mig > 0.0) & (mig <= snap)
-        mig_rem = jnp.where(mig_done, 0.0,
-                            jnp.where(mig > 0.0,
-                                      jnp.maximum(mig - dt, 0.0), mig))
-        vms = dataclasses.replace(vms, mig_remaining=mig_rem)
+        vms = dc.vms
+        if dynamic:
+            # migration copy countdown — a delta like cloudlet ``remaining``,
+            # with the same completion snap band so the resume event lands on
+            # the same step on both the engine and the f64 oracle.
+            mig = vms.mig_remaining
+            mig_done = (mig > 0.0) & (mig <= snap)
+            mig_rem = jnp.where(mig_done, 0.0,
+                                jnp.where(mig > 0.0,
+                                          jnp.maximum(mig - dt, 0.0), mig))
+            vms = dataclasses.replace(vms, mig_remaining=mig_rem)
 
-    scaler = dc.scaler
-    if elastic:
-        # spot spend: price and alive fleet are constant on [time, time+dt)
-        # (fleet only changes inside the passes above), so price * fleet *
-        # dt is the exact integral — like energy.  Zero-price when the
-        # track is disabled, so the accrual is a bit-exact identity then.
-        spot_rate = (market.spot_price_at(scaler, dc.time)
-                     * alive_fleet(dc.vms).astype(jnp.float32))
-        scaler = dataclasses.replace(
-            scaler, spot_cost=scaler.spot_cost + spot_rate * dt)
+        scaler = dc.scaler
+        if elastic:
+            # spot spend: price and alive fleet are constant on [time, time+dt)
+            # (fleet only changes inside the passes above), so price * fleet *
+            # dt is the exact integral — like energy.  Zero-price when the
+            # track is disabled, so the accrual is a bit-exact identity then.
+            spot_rate = (market.spot_price_at(scaler, dc.time)
+                         * alive_fleet(dc.vms).astype(jnp.float32))
+            scaler = dataclasses.replace(
+                scaler, spot_cost=scaler.spot_cost + spot_rate * dt)
 
-    new = dataclasses.replace(
-        dc,
-        hosts=dataclasses.replace(dc.hosts, energy_j=energy_j),
-        vms=vms,
-        cloudlets=dataclasses.replace(
-            cl, remaining=remaining, start_time=start_time,
-            finish_time=finish_time, state=state, net_phase=net_phase,
-            net_lat=net_lat, net_remaining=net_rem),
-        acct=dataclasses.replace(dc.acct, cpu_cost=cpu_cost, bw_cost=bw_cost),
-        time=t_next,
-        net_transferred_mb=transferred_mb,
-        scaler=scaler,
-    )
+        new = dataclasses.replace(
+            dc,
+            hosts=dataclasses.replace(dc.hosts, energy_j=energy_j),
+            vms=vms,
+            cloudlets=dataclasses.replace(
+                cl, remaining=remaining, start_time=start_time,
+                finish_time=finish_time, state=state, net_phase=net_phase,
+                net_lat=net_lat, net_remaining=net_rem),
+            acct=dataclasses.replace(dc.acct, cpu_cost=cpu_cost,
+                                     bw_cost=bw_cost),
+            time=t_next,
+            net_transferred_mb=transferred_mb,
+            scaler=scaler,
+        )
 
     if probed:
-        new = _probe_commit(dc, new, rates, host_watts, dt,
-                            frates if networked else None, was_done)
+        with jax.named_scope("probes"):
+            new = _probe_commit(dc, new, rates, host_watts, dt,
+                                frates if networked else None, was_done)
 
     n_events = active.astype(jnp.int32)
     if leap:
-        new, extra = _leap_window(
-            dc, new, rates, active, dt_arr, dt_other, arrive,
-            trig_next if dynamic else None,
-            mig_done if dynamic else None,
-            leap_budget, leap_horizon,
-            next_arrival if streaming else None,
-            dynamic=dynamic, networked=networked, streaming=streaming,
-            elastic=elastic, probed=probed)
-        n_events = n_events + extra
+        with jax.named_scope("leap"):
+            new, extra = _leap_window(
+                dc, new, rates, active, dt_arr, dt_other, arrive,
+                trig_next if dynamic else None,
+                mig_done if dynamic else None,
+                leap_budget, leap_horizon,
+                next_arrival if streaming else None,
+                dynamic=dynamic, networked=networked, streaming=streaming,
+                elastic=elastic, probed=probed)
+            n_events = n_events + extra
 
-    host_mips = jnp.sum(jnp.where(dc.hosts.valid,
-                                  dc.hosts.capacity_mips, 0.0))
-    rec = StepRecord(
-        time=new.time,
-        n_running=jnp.sum((rates > 0.0).astype(jnp.int32)),
-        n_done=jnp.sum((new.cloudlets.state == CL_DONE).astype(jnp.int32)),
-        utilization=jnp.sum(rates) / jnp.maximum(host_mips, 1e-30),
-        watts=jnp.sum(host_watts),
-        active=active,
-        n_migrating=jnp.sum((new.vms.mig_remaining > 0.0
-                             ).astype(jnp.int32)),
-        migrations=new.mig_count,
-        hosts_down=jnp.sum((~new.hosts.valid
-                            & (new.hosts.num_pes > 0)).astype(jnp.int32)),
-        transferred_mb=new.net_transferred_mb,
-        n_flows=(jnp.sum((frates > 0.0).astype(jnp.int32)) if networked
-                 else jnp.int32(0)),
-        n_events=n_events,
-        fleet=alive_fleet(new.vms),
-        spot_cost=new.scaler.spot_cost,
-    )
+    with jax.named_scope("record"):
+        host_mips = jnp.sum(jnp.where(dc.hosts.valid,
+                                      dc.hosts.capacity_mips, 0.0))
+        rec = StepRecord(
+            time=new.time,
+            n_running=jnp.sum((rates > 0.0).astype(jnp.int32)),
+            n_done=jnp.sum((new.cloudlets.state == CL_DONE).astype(jnp.int32)),
+            utilization=jnp.sum(rates) / jnp.maximum(host_mips, 1e-30),
+            watts=jnp.sum(host_watts),
+            active=active,
+            n_migrating=jnp.sum((new.vms.mig_remaining > 0.0
+                                 ).astype(jnp.int32)),
+            migrations=new.mig_count,
+            hosts_down=jnp.sum((~new.hosts.valid
+                                & (new.hosts.num_pes > 0)).astype(jnp.int32)),
+            transferred_mb=new.net_transferred_mb,
+            n_flows=(jnp.sum((frates > 0.0).astype(jnp.int32)) if networked
+                     else jnp.int32(0)),
+            n_events=n_events,
+            fleet=alive_fleet(new.vms),
+            spot_cost=new.scaler.spot_cost,
+        )
     return new, rec
 
 
@@ -971,25 +1000,25 @@ def wants_probes(dc: DatacenterState) -> bool:
 def _run(dc: DatacenterState, *, max_steps: int, horizon: float,
          provision_policy: int, dynamic: bool,
          networked: bool, elastic: bool, leap: bool,
-         probed: bool) -> DatacenterState:
+         probed: bool) -> tuple[DatacenterState, RunStats]:
     horizon = jnp.minimum(jnp.asarray(horizon, jnp.float32), INF)
 
     def cond(carry):
-        dc, n, alive = carry
+        dc, n, _, alive = carry
         return alive & (n < max_steps) & (dc.time < horizon)
 
     def body(carry):
-        dc, n, _ = carry
+        dc, n, it, _ = carry
         new, rec = step(dc, provision_policy=provision_policy,
                         dynamic=dynamic, networked=networked,
                         elastic=elastic, leap=leap,
                         leap_budget=jnp.int32(max_steps) - n - 1,
                         leap_horizon=horizon, probed=probed)
-        return new, n + rec.n_events, rec.active
+        return new, n + rec.n_events, it + 1, rec.active
 
-    out, _, _ = jax.lax.while_loop(cond, body, (dc, jnp.int32(0),
-                                                jnp.bool_(True)))
-    return out
+    out, n, it, _ = jax.lax.while_loop(
+        cond, body, (dc, jnp.int32(0), jnp.int32(0), jnp.bool_(True)))
+    return out, RunStats(iterations=it, events=n)
 
 
 def run(dc: DatacenterState, *, max_steps: int = 1_000_000,
@@ -998,7 +1027,8 @@ def run(dc: DatacenterState, *, max_steps: int = 1_000_000,
         networked: bool | None = None,
         elastic: bool | None = None,
         leap: bool | None = None,
-        probed: bool | None = None) -> DatacenterState:
+        probed: bool | None = None, stats: bool = False
+        ) -> DatacenterState | tuple[DatacenterState, RunStats]:
     """Run the simulation to quiescence with ``lax.while_loop``.
 
     Terminates when the event queue is empty (no runnable work, no future
@@ -1015,21 +1045,31 @@ def run(dc: DatacenterState, *, max_steps: int = 1_000_000,
     iteration commits a run of queued completions (``_leap_window``) —
     bit-for-bit identical results, fewer iterations.  ``leap=False``
     forces the one-event-per-iteration program (parity tests).
+
+    ``stats=True`` returns ``(final, RunStats)``: the loop's iteration
+    count and the events it retired (scalars).  Both settings run the
+    same compiled program.  The call is wrapped in the host spans
+    ``repro.run`` > ``repro.run.flags`` (the ``wants_*`` detection) and
+    ``repro.run.launch`` (the asynchronous call into the program).
     """
-    if dynamic is None:
-        dynamic = wants_dynamic(dc)
-    if networked is None:
-        networked = wants_network(dc)
-    if elastic is None:
-        elastic = wants_elastic(dc)
-    if leap is None:
-        leap = _LEAP_DEFAULT
-    if probed is None:
-        probed = wants_probes(dc)
-    return _run(dc, max_steps=max_steps, horizon=horizon,
-                provision_policy=provision_policy, dynamic=dynamic,
-                networked=networked, elastic=elastic, leap=leap,
-                probed=probed)
+    with _span("repro.run"):
+        with _span("repro.run.flags"):
+            if dynamic is None:
+                dynamic = wants_dynamic(dc)
+            if networked is None:
+                networked = wants_network(dc)
+            if elastic is None:
+                elastic = wants_elastic(dc)
+            if leap is None:
+                leap = _LEAP_DEFAULT
+            if probed is None:
+                probed = wants_probes(dc)
+        with _span("repro.run.launch"):
+            out, run_stats = _run(dc, max_steps=max_steps, horizon=horizon,
+                                  provision_policy=provision_policy,
+                                  dynamic=dynamic, networked=networked,
+                                  elastic=elastic, leap=leap, probed=probed)
+    return (out, run_stats) if stats else out
 
 
 @partial(jax.jit, static_argnames=("num_steps", "provision_policy",
@@ -1111,7 +1151,8 @@ def batched_run(batch: DatacenterState, *, max_steps: int,
                 provision_policy: int = FIRST_FIT, dynamic: bool = True,
                 networked: bool = False, elastic: bool = False,
                 leap: bool = _LEAP_DEFAULT,
-                probed: bool = False) -> DatacenterState:
+                probed: bool = False
+                ) -> tuple[DatacenterState, RunStats]:
     """Run a batched state (leading lane axis) to quiescence.
 
     Equivalent to ``vmap(run)`` lane for lane — finished lanes are frozen
@@ -1127,6 +1168,11 @@ def batched_run(batch: DatacenterState, *, max_steps: int,
     for lanes ``_lane_dynamic`` rejects (no due events, no trigger, no
     copy countdown — each gated pass skips), so switching variants
     mid-run never perturbs results.
+
+    Returns ``(final, RunStats)`` with one counter per lane: loop trips
+    while the lane was live, and the events it retired.  The per-lane
+    select that freezes finished lanes runs under the named scope
+    ``freeze``.
     """
     hor = jnp.minimum(jnp.asarray(horizon, jnp.float32), INF)
     lanes = batch.time.shape[0]
@@ -1139,7 +1185,7 @@ def batched_run(batch: DatacenterState, *, max_steps: int,
         return lambda op: jax.vmap(one)(op[0], op[1])
 
     def body(carry):
-        b, n, alive = carry
+        b, n, it, alive = carry
         live = alive & (n < max_steps) & (b.time < hor)
         bud = jnp.int32(max_steps) - n - 1
         op = (b, bud)
@@ -1173,21 +1219,23 @@ def batched_run(batch: DatacenterState, *, max_steps: int,
             new, rec = dispatch(list(need), {})(op)
         # freeze finished lanes — the batching rule vmap applies to
         # while_loop, replicated here leaf by leaf
-        sel = lambda a, o: jnp.where(
-            live.reshape(live.shape + (1,) * (a.ndim - 1)), a, o)
-        b2 = jax.tree.map(sel, new, b)
-        n2 = jnp.where(live, n + rec.n_events, n)
-        alive2 = jnp.where(live, rec.active, alive)
-        return b2, n2, alive2
+        with jax.named_scope("freeze"):
+            sel = lambda a, o: jnp.where(
+                live.reshape(live.shape + (1,) * (a.ndim - 1)), a, o)
+            b2 = jax.tree.map(sel, new, b)
+            n2 = jnp.where(live, n + rec.n_events, n)
+            it2 = jnp.where(live, it + 1, it)
+            alive2 = jnp.where(live, rec.active, alive)
+        return b2, n2, it2, alive2
 
     def cond(carry):
-        b, n, alive = carry
+        b, n, _, alive = carry
         return jnp.any(alive & (n < max_steps) & (b.time < hor))
 
-    out, _, _ = jax.lax.while_loop(
-        cond, body, (batch, jnp.zeros((lanes,), jnp.int32),
-                     jnp.ones((lanes,), bool)))
-    return out
+    zeros = jnp.zeros((lanes,), jnp.int32)
+    out, n, it, _ = jax.lax.while_loop(
+        cond, body, (batch, zeros, zeros, jnp.ones((lanes,), bool)))
+    return out, RunStats(iterations=it, events=n)
 
 
 # ---------------------------------------------------------------------------

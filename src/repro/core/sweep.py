@@ -183,6 +183,9 @@ def stack_scenarios(dcs: Sequence[DatacenterState]) -> DatacenterState:
 # ---------------------------------------------------------------------------
 # Batched runners
 # ---------------------------------------------------------------------------
+# Host spans on the profiler's clock (docs/observability.md)
+_span = jax.profiler.TraceAnnotation
+
 def _run_batch(batch: DatacenterState, *, max_steps: int,
                provision_policy: int, dynamic: bool,
                networked: bool, elastic: bool = False,
@@ -190,10 +193,11 @@ def _run_batch(batch: DatacenterState, *, max_steps: int,
     # engine.batched_run == vmap(engine.run) lane for lane (bitwise), plus
     # the dead-lane early-exit: the dynamic/networked/elastic passes switch
     # off the moment no live lane needs them (tests/test_leap_parity.py).
-    return engine.batched_run(batch, max_steps=max_steps,
-                              provision_policy=provision_policy,
-                              dynamic=dynamic, networked=networked,
-                              elastic=elastic, probed=probed)
+    out, _ = engine.batched_run(batch, max_steps=max_steps,
+                                provision_policy=provision_policy,
+                                dynamic=dynamic, networked=networked,
+                                elastic=elastic, probed=probed)
+    return out
 
 
 def run_batch(batch: DatacenterState, *, max_steps: int = 1_000_000,
@@ -395,7 +399,8 @@ def _dispatch_cost(batch: DatacenterState) -> np.ndarray:
 def _dispatch_run(batch: DatacenterState, mesh, *, max_steps: int,
                   provision_policy: int, dynamic: bool, networked: bool,
                   elastic: bool = False, probed: bool = False,
-                  chunk: int = 4) -> DatacenterState:
+                  chunk: int = 4
+                  ) -> tuple[DatacenterState, engine.RunStats]:
     """Sorted-chunk dispatch: per-call sharding without SPMD.
 
     Lanes are sorted by estimated cost (descending) and cut into
@@ -409,7 +414,8 @@ def _dispatch_run(batch: DatacenterState, mesh, *, max_steps: int,
     neither CPU-partitioner landmine (vmapped-step crash, loop-variant
     sort rendezvous) is reachable.  Results are reassembled in original
     lane order; per-lane bitwise equality to the fused path follows from
-    ``batched_run`` == ``vmap(run)``.
+    ``batched_run`` == ``vmap(run)``.  Returns ``(final, RunStats)``, both
+    in the original lane order.
     """
     devs = list(mesh.devices.flat)
     order = np.argsort(-_dispatch_cost(batch), kind="stable")
@@ -427,7 +433,9 @@ def _dispatch_run(batch: DatacenterState, mesh, *, max_steps: int,
         lambda *xs: jnp.concatenate([jax.device_put(x, devs[0])
                                      for x in xs]), *outs)
     inv = jnp.asarray(np.argsort(order, kind="stable"))
-    return jax.tree_util.tree_map(lambda x: jnp.take(x, inv, axis=0), cat)
+    out, run_stats = jax.tree_util.tree_map(
+        lambda x: jnp.take(x, inv, axis=0), cat)
+    return out, run_stats
 
 
 def _default_inner() -> str:
@@ -542,10 +550,11 @@ def run_sharded(batch: DatacenterState, *, mesh=None, axis: str = "sweep",
                                        dispatch_ok=True)
     if partitioner == "dispatch":
         # chunks need no divisibility padding — any lane count dispatches
-        return _dispatch_run(batch, mesh, max_steps=max_steps,
-                             provision_policy=provision_policy,
-                             dynamic=dynamic, networked=networked,
-                             elastic=elastic, probed=probed)
+        out, _ = _dispatch_run(batch, mesh, max_steps=max_steps,
+                               provision_policy=provision_policy,
+                               dynamic=dynamic, networked=networked,
+                               elastic=elastic, probed=probed)
+        return out
     have = batch.time.shape[0]
     lanes = -(-have // n_dev) * n_dev
     padded = pad_batch(batch, lanes)
@@ -573,13 +582,16 @@ def _grid_runner(mesh, max_steps: int, provision_policy: int,
     vmap, and the [P, B] reshape — traces into a single XLA program, so
     the P-fold broadcast of the scenario batch is never materialized on
     the host side.  ``mesh=None`` is the unsharded single-device variant.
+    The program is named ``run_grid`` in a trace and returns ``(final,
+    RunStats)``, both [P, B].
     """
     run_lane = lambda dc: engine.run(dc, max_steps=max_steps,
                                      provision_policy=provision_policy,
                                      dynamic=dynamic, networked=networked,
-                                     elastic=elastic, probed=probed)
+                                     elastic=elastic, probed=probed,
+                                     stats=True)
 
-    def fn(batch, vm_policies, task_policies):
+    def run_grid(batch, vm_policies, task_policies):
         n_pol = vm_policies.shape[0]
         n_scen = batch.time.shape[0]
         fused = fuse_grid(batch, vm_policies, task_policies)
@@ -609,7 +621,7 @@ def _grid_runner(mesh, max_steps: int, provision_policy: int,
         return jax.tree_util.tree_map(
             lambda x: x.reshape((n_pol, n_scen) + x.shape[1:]), out)
 
-    return jax.jit(fn)
+    return jax.jit(run_grid)
 
 
 def run_grid(batch: DatacenterState, vm_policies: jnp.ndarray,
@@ -620,7 +632,8 @@ def run_grid(batch: DatacenterState, vm_policies: jnp.ndarray,
              dynamic: bool | None = None,
              networked: bool | None = None,
              elastic: bool | None = None,
-             probed: bool | None = None) -> DatacenterState:
+             probed: bool | None = None, stats: bool = False
+             ) -> DatacenterState | tuple[DatacenterState, engine.RunStats]:
     """Scenarios x policy grid as ONE fused, device-sharded batch.
 
     ``vm_policies``/``task_policies`` are i32[P] (paired — e.g. the 2x2
@@ -635,43 +648,59 @@ def run_grid(batch: DatacenterState, vm_policies: jnp.ndarray,
     Every lane is bit-for-bit equal to the corresponding single
     ``engine.run`` (and to ``run_grid_nested``): fusing and sharding
     change the schedule, never the per-lane math.
+
+    ``stats=True`` returns ``(final, RunStats)``, the counters i32[P, B]:
+    each lane's loop trips while live and its events retired.  Both
+    settings run the same compiled program.  The call is wrapped in the
+    host spans ``repro.grid`` > ``repro.grid.flags`` (policy arrays,
+    sharding, the ``wants_*`` detection) and ``repro.grid.launch`` (the
+    runner lookup and the asynchronous call).
     """
-    vm_policies = jnp.asarray(vm_policies, jnp.int32)
-    task_policies = jnp.asarray(task_policies, jnp.int32)
-    if vm_policies.shape != task_policies.shape:
-        raise ValueError("vm_policies and task_policies must pair up: "
-                         f"{vm_policies.shape} vs {task_policies.shape}")
-    if sharded is None:
-        sharded = mesh is not None or jax.device_count() > 1
-    if sharded and mesh is None:
-        mesh = compat.make_mesh("sweep")
-    if not sharded:
-        mesh = None
-    if dynamic is None:
-        dynamic = engine.wants_dynamic(batch)
-    if networked is None:
-        networked = engine.wants_network(batch)
-    if elastic is None:
-        elastic = engine.wants_elastic(batch)
-    if probed is None:
-        probed = engine.wants_probes(batch)
-    n_dev = mesh.shape[_lane_axis(mesh)] if mesh is not None else 1
-    resolved = _resolve_partitioner(partitioner, n_dev=n_dev,
-                                    dispatch_ok=mesh is not None)
-    if resolved == "dispatch":
-        # host-side path: materialize the fused grid once, dispatch
-        # sorted chunks, reshape back — same [P, B] layout as _grid_runner
-        n_pol, n_scen = int(vm_policies.shape[0]), int(batch.time.shape[0])
-        fused = fuse_grid(batch, vm_policies, task_policies)
-        out = _dispatch_run(fused, mesh, max_steps=max_steps,
-                            provision_policy=provision_policy,
-                            dynamic=dynamic, networked=networked,
-                            elastic=elastic, probed=probed)
-        return jax.tree_util.tree_map(
-            lambda x: x.reshape((n_pol, n_scen) + x.shape[1:]), out)
-    return _grid_runner(mesh, max_steps, provision_policy, resolved,
-                        _default_inner(), dynamic, networked,
-                        elastic, probed)(batch, vm_policies, task_policies)
+    with _span("repro.grid"):
+        with _span("repro.grid.flags"):
+            vm_policies = jnp.asarray(vm_policies, jnp.int32)
+            task_policies = jnp.asarray(task_policies, jnp.int32)
+            if vm_policies.shape != task_policies.shape:
+                raise ValueError(
+                    "vm_policies and task_policies must pair up: "
+                    f"{vm_policies.shape} vs {task_policies.shape}")
+            if sharded is None:
+                sharded = mesh is not None or jax.device_count() > 1
+            if sharded and mesh is None:
+                mesh = compat.make_mesh("sweep")
+            if not sharded:
+                mesh = None
+            if dynamic is None:
+                dynamic = engine.wants_dynamic(batch)
+            if networked is None:
+                networked = engine.wants_network(batch)
+            if elastic is None:
+                elastic = engine.wants_elastic(batch)
+            if probed is None:
+                probed = engine.wants_probes(batch)
+            n_dev = mesh.shape[_lane_axis(mesh)] if mesh is not None else 1
+            resolved = _resolve_partitioner(partitioner, n_dev=n_dev,
+                                            dispatch_ok=mesh is not None)
+        with _span("repro.grid.launch"):
+            if resolved == "dispatch":
+                # host-side path: materialize the fused grid once, dispatch
+                # sorted chunks, reshape back — the [P, B] layout of
+                # _grid_runner
+                n_pol = int(vm_policies.shape[0])
+                n_scen = int(batch.time.shape[0])
+                fused = fuse_grid(batch, vm_policies, task_policies)
+                flat = _dispatch_run(fused, mesh, max_steps=max_steps,
+                                     provision_policy=provision_policy,
+                                     dynamic=dynamic, networked=networked,
+                                     elastic=elastic, probed=probed)
+                out, run_stats = jax.tree_util.tree_map(
+                    lambda x: x.reshape((n_pol, n_scen) + x.shape[1:]), flat)
+            else:
+                out, run_stats = _grid_runner(
+                    mesh, max_steps, provision_policy, resolved,
+                    _default_inner(), dynamic, networked, elastic,
+                    probed)(batch, vm_policies, task_policies)
+    return (out, run_stats) if stats else out
 
 
 def policy_grid() -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -1039,23 +1068,27 @@ class SweepSummary(NamedTuple):
 
 
 def summarize_batch(final: DatacenterState) -> SweepSummary:
-    """Reduce a batched final state (any leading batch dims) to summaries."""
-    cl = final.cloudlets
-    done = cl.state == CL_DONE
-    n_done = jnp.sum(done.astype(jnp.int32), axis=-1)
-    makespan = jnp.max(jnp.where(done, cl.finish_time, 0.0), axis=-1)
-    resp = jnp.where(done, cl.finish_time - cl.submit_time, 0.0)
-    denom = jnp.maximum(n_done.astype(jnp.float32), 1.0)
-    return SweepSummary(
-        n_done=n_done,
-        makespan=makespan,
-        mean_response=jnp.sum(resp, axis=-1) / denom,
-        total_cost=final.acct.total,
-        energy_j=energy_total_j(final),
-        n_migrations=final.mig_count,
-        mig_downtime=final.mig_downtime,
-        transferred_mb=final.net_transferred_mb,
-        spot_cost=final.scaler.spot_cost,
-        n_scale_up=final.scaler.up_count,
-        n_scale_down=final.scaler.down_count,
-    )
+    """Reduce a batched final state (any leading batch dims) to summaries.
+
+    Eager reductions, under the host span ``repro.summarize``; the
+    caller's fetch of the summary lies outside it."""
+    with _span("repro.summarize"):
+        cl = final.cloudlets
+        done = cl.state == CL_DONE
+        n_done = jnp.sum(done.astype(jnp.int32), axis=-1)
+        makespan = jnp.max(jnp.where(done, cl.finish_time, 0.0), axis=-1)
+        resp = jnp.where(done, cl.finish_time - cl.submit_time, 0.0)
+        denom = jnp.maximum(n_done.astype(jnp.float32), 1.0)
+        return SweepSummary(
+            n_done=n_done,
+            makespan=makespan,
+            mean_response=jnp.sum(resp, axis=-1) / denom,
+            total_cost=final.acct.total,
+            energy_j=energy_total_j(final),
+            n_migrations=final.mig_count,
+            mig_downtime=final.mig_downtime,
+            transferred_mb=final.net_transferred_mb,
+            spot_cost=final.scaler.spot_cost,
+            n_scale_up=final.scaler.up_count,
+            n_scale_down=final.scaler.down_count,
+        )
