@@ -115,13 +115,20 @@ def host_power(hosts, util: jnp.ndarray) -> jnp.ndarray:
     ``util`` (clamped to [0, 1]), scaled into [idle_w, peak_w].  Invalid
     (padded) hosts draw exactly 0 W, which keeps scenario padding and
     inert sweep lanes energy-neutral.
+
+    The knot lookup is a select over the ``K_CURVE`` knots summed along
+    the knot axis, not a per-host gather: a TPU gathers one element at a
+    time, while the select fuses into elementwise work.  Each sum adds one
+    knot to exact zeros, so it is bit-exact with the gather.
     """
     u = jnp.clip(util, 0.0, 1.0) * (K_CURVE - 1)
     lo = jnp.clip(u.astype(jnp.int32), 0, K_CURVE - 2)    # i32[H]
     frac = u - lo.astype(jnp.float32)
-    c_lo = jnp.take_along_axis(hosts.power_curve, lo[:, None], axis=1)[:, 0]
-    c_hi = jnp.take_along_axis(hosts.power_curve, (lo + 1)[:, None],
-                               axis=1)[:, 0]
+    k = jnp.arange(K_CURVE, dtype=jnp.int32)
+    knot = lambda i: jnp.sum(jnp.where(k == i[:, None], hosts.power_curve,
+                                       0.0), axis=1)
+    c_lo = knot(lo)
+    c_hi = knot(lo + 1)
     c = c_lo + (c_hi - c_lo) * frac
     watts = hosts.idle_w + (hosts.peak_w - hosts.idle_w) * c
     return jnp.where(hosts.valid, watts, 0.0)
