@@ -1,16 +1,18 @@
 """One run of one cell: set-up, a closed loop of studies, the check.
 
-Set-up builds the cell's scenario, compiles (or fetches
-from the persistent cache) by running one warm-up study of the cell's
-own shapes, and ends when the first timed study starts.  The window is
-a closed loop: the next study starts once the previous study's summary
-is on the host, as a researcher's script or sweep loop runs them, and
+Set-up builds the cell's scenario through its deployment kind
+(``deployments/<kind>.py``, found by ``chipbench.spec``), compiles (or
+fetches from the persistent cache) by running one warm-up study of the
+cell's own shapes, and ends when the first timed study starts.  The
+window is a closed loop: the next study starts once the previous study's
+summary is on the host, as a researcher's script or sweep loop runs them, and
 the window ends with the first study that ends ``--seconds`` after the
 window began.  With ``--trace 1`` the profiler records the studies that
 start in the window's first ``TRACE_SECONDS``, and the run reports the
 per-layer metrics; with ``--trace 0`` it reports the end-to-end ones.
-Once the window has closed, the studies' outputs are compared with the
-plain reference (``chipbench.check``).
+Once the window has closed, the kind compares the studies' outputs with
+its plain reference, and ``chipbench.check`` holds each number to its
+limit.
 
 The last line on standard output is one JSON object; the last lines on
 standard error are the compared numbers beside their limits.
@@ -29,7 +31,6 @@ from typing import List, Optional
 
 from chipbench import check, spec, tracing
 from chipbench.clock import CompileClock
-from chipbench.traffic import Mix
 
 ROOT = os.path.dirname(os.path.dirname(spec.BENCH_DIR))
 TRACE_SECONDS = 2.0
@@ -40,7 +41,7 @@ class StudyRun:
     index: int
     start: float                # host clock, s
     end: float
-    cloudlets: int              # CL_DONE summed over the study's lanes
+    cloudlets: int              # completed, summed over the study's lanes
     lanes_short: int
 
 
@@ -105,12 +106,12 @@ def run(args, t_start: float, *, root: str = ROOT,
 
 def _run_cell(args, t_start, cell, devices, clock) -> dict:
     import jax
-    from chipbench.sut import System
 
+    kind, used = cell.kind, devices[:cell.chips]
     seed = args.seed % 2**64
     t0 = time.perf_counter()
-    mix = Mix(cell.config, cell.traffic, seed)
-    system = System(mix, cell.chips)
+    mix = kind.make_mix(cell.config, cell.traffic, seed)
+    system = kind.System(mix, used)
     build_s = time.perf_counter() - t0
     warm = system.dispatch(system.prepare(mix.warmup()))
     system.summary(warm)
@@ -140,13 +141,12 @@ def _run_cell(args, t_start, cell, devices, clock) -> dict:
             with span("wait"):
                 jax.block_until_ready(out)
             with span("fetch"):
-                summary = system.summary(out)
+                cloudlets, short = system.summary(out)
             s1 = time.perf_counter()
         kept.append(system.keep(out))
         del out, dc
         studies.append(study)
-        runs.append(StudyRun(i, s0, s1, int(summary.n_done.sum()),
-                             check.lanes_short(mix, summary.n_done)))
+        runs.append(StudyRun(i, s0, s1, cloudlets, short))
         i += 1
         if tracing_on and s1 - w0 >= min(TRACE_SECONDS, args.seconds):
             jax.profiler.stop_trace()
@@ -155,7 +155,7 @@ def _run_cell(args, t_start, cell, devices, clock) -> dict:
             break
     compiles_in_window = len(clock.spans) - n_spans
     peak = system.peak_bytes()
-    device_ids = [d.id for d in system.devices]
+    device_ids = [d.id for d in used]
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": peak}
@@ -163,11 +163,11 @@ def _run_cell(args, t_start, cell, devices, clock) -> dict:
     # -- correct: the window's outputs against the plain reference, once
     # they are on the host and the program's device state is freed --------
     t_check = time.perf_counter()
-    outputs = [o._asdict() for o in jax.device_get(kept)]
+    outputs = jax.device_get(kept)
     del kept, system
-    readings, n_checked = check.compare(
-        mix, studies, check.study_outputs(mix, outputs))
+    readings = kind.readings(mix, studies, outputs)
     readings["lanes_short"] = sum(r.lanes_short for r in runs)
+    n_checked = sum(mix.lanes(s) for s in studies)
     correct, table = check.verdict(readings, cell.config["checks"])
     correct = correct and n_checked > 0
     ms = sorted(1e3 * (r.end - r.start) for r in runs)

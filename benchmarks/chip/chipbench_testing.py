@@ -1,7 +1,6 @@
 """Helpers of the benchmark's own tests: a copy of the benchmark's files
 with its deployments cut to a size the CPU runs in a second."""
 import argparse
-import glob
 import json
 import os
 import shutil
@@ -14,31 +13,27 @@ SRC = os.path.join(REPO, "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
-# (hosts, vms, waves) of each deployment, and the replicates of each grid
-# mix, cut down
-TINY = {"paper_fig89": (32, 4, 3)}
-TINY_REPLICATES = 2
-
 
 def tiny_root(tmp_path):
     """(root, bench_dir): BENCHMARK.json and the benchmark's data files
-    under ``tmp_path``, with every deployment and grid cut down."""
+    under ``tmp_path``, with every cell's configuration and traffic cut
+    down by its deployment kind's ``tiny``."""
+    from chipbench import spec
     root = str(tmp_path / "checkout")
     bench_dir = os.path.join(root, "benchmarks", "chip")
-    for sub in ("configs", "traffic", "metrics"):
-        shutil.copytree(os.path.join(HERE, sub), os.path.join(bench_dir, sub))
+    for sub in ("configs", "traffic", "metrics", "deployments"):
+        shutil.copytree(os.path.join(HERE, sub), os.path.join(bench_dir, sub),
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
-    for name, (hosts, vms, waves) in TINY.items():
-        path = os.path.join(bench_dir, "configs", f"{name}.json")
-        config = load(path)
-        config["hosts"]["count"], config["vms"]["count"] = hosts, vms
-        config["cloudlets"]["waves"] = waves
-        dump(path, config)
-    for path in glob.glob(os.path.join(bench_dir, "traffic", "*.json")):
-        traffic = load(path)
-        if "replicates" in traffic:
-            traffic["replicates"] = TINY_REPLICATES
-            dump(path, traffic)
+    bench = load(os.path.join(REPO, "BENCHMARK.json"))
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    for w in bench["workloads"]:
+        config = load(os.path.join(REPO, files[w["config"]]))
+        traffic_file = os.path.join("traffic", f"{w['traffic']}.json")
+        traffic = load(os.path.join(HERE, traffic_file))
+        config, traffic = spec.deployment_kind(config).tiny(config, traffic)
+        dump(os.path.join(root, files[w["config"]]), config)
+        dump(os.path.join(bench_dir, traffic_file), traffic)
     return root, bench_dir
 
 

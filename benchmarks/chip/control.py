@@ -4,11 +4,12 @@
         --seeds <a> <b> <c> ...
 
 For each seed it makes the studies a run of the cell would make (the
-first ``--studies`` of them), takes as the "program's" output of every
-lane the plain reference computed in bfloat16 (the nearest
-precision below the engine's float32), and runs the same comparison a
-run makes.  A sound comparison refuses it: ``correct`` has to come out
-false.  The benchmark's own runs never run this; it needs no chip.
+first ``--studies`` of them), takes as the "program's" outputs of every
+study those of the cell's deployment kind's plain reference computed in
+bfloat16 (the nearest precision below the engine's float32), and runs
+the same comparison a run makes.  A sound comparison refuses it:
+``correct`` has to come out false.  The benchmark's own runs never run
+this; it needs no chip.
 """
 import argparse
 import json
@@ -17,24 +18,16 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from chipbench import check, reference, spec  # noqa: E402
-from chipbench.traffic import Mix  # noqa: E402
+from chipbench import check, spec  # noqa: E402
 
 
 def control(cell, seed: int, n_studies: int):
     """(correct, each number beside its limit) of the control."""
-    mix = Mix(cell.config, cell.traffic, seed)
+    kind = cell.kind
+    mix = kind.make_mix(cell.config, cell.traffic, seed)
     studies = [mix.study(i) for i in range(n_studies)]
-    low = {}
-
-    def output(s, p):
-        pair = studies[s][p]
-        if pair not in low:
-            low[pair] = check.result_arrays(reference.simulate(
-                mix.lane(pair), precision="bfloat16"))
-        return low[pair]
-
-    readings, _ = check.compare(mix, studies, output)
+    outputs = kind.reference_outputs(mix, studies, "bfloat16")
+    readings = kind.readings(mix, studies, outputs)
     readings["lanes_short"] = 0
     return check.verdict(readings, cell.config["checks"])
 

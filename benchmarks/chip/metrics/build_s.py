@@ -1,5 +1,6 @@
-"""Scenario build in set-up: the generator, ``state.make_*``,
-``sweep.stack_scenarios`` and the transfer to the chip (host clock)."""
+"""Scenario build in set-up: the deployment kind's generator
+(``make_mix``) and ``System``, which builds the scenario and puts it on
+the chips (host clock)."""
 
 
 def read(record):

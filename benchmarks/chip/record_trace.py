@@ -32,8 +32,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path[:0] = [HERE, os.path.join(REPO, "src")]
 
-from chipbench import check, program_trace, spec, tracing  # noqa: E402
-from chipbench.traffic import Mix  # noqa: E402
+from chipbench import program_trace, spec, tracing  # noqa: E402
 
 SEED = 2**31 + 101
 
@@ -43,15 +42,15 @@ class Study:
     index: int
     start: float                # host clock, s
     end: float
-    cloudlets: int              # CL_DONE summed over the study's lanes
+    cloudlets: int              # completed, summed over the study's lanes
     lanes_short: int
     iterations: int             # loop trips, the most of any lane
     events: int                 # events retired, summed over lanes
 
 
 def dispatch_counted(system, inputs):
-    """``System.dispatch`` with the program's loop counters on:
-    ``(final state, RunStats)``."""
+    """``System.dispatch`` of the ``waves`` deployment kind with the
+    program's loop counters on: ``(final state, RunStats)``."""
     dc, vm_p, task_p = inputs
     steps = system.mix.max_steps
     if system.mix.runner == "engine.run":
@@ -77,11 +76,10 @@ def run_studies(jax, system, mix, first: int, count: int):
             with span("wait"):
                 jax.block_until_ready(final)
             with span("fetch"):
-                summary = system.summary(final)
+                cloudlets, short = system.summary(final)
             s1 = time.perf_counter()
         stats = jax.device_get(stats)
-        out.append(Study(i, s0, s1, int(summary.n_done.sum()),
-                         check.lanes_short(mix, summary.n_done),
+        out.append(Study(i, s0, s1, cloudlets, short,
                          int(np.max(stats.iterations)),
                          int(np.sum(stats.events))))
     return out
@@ -97,9 +95,8 @@ def record(a, require_tpu: bool = True):
     if require_tpu and jax.devices()[0].platform != "tpu":
         print("[record] refusing to run: no TPU", file=sys.stderr)
         return 3, None
-    from chipbench.sut import System
-    mix = Mix(cell.config, cell.traffic, SEED)
-    system = System(mix, cell.chips)
+    mix = cell.kind.make_mix(cell.config, cell.traffic, SEED)
+    system = cell.kind.System(mix, jax.devices()[:cell.chips])
     warm, _ = dispatch_counted(system, system.prepare(mix.warmup()))
     system.summary(warm)
     del warm

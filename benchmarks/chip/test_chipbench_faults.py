@@ -4,13 +4,16 @@ fails.  The harness runs here on the CPU, past its look for a chip, at a
 size the CPU runs in a second."""
 import dataclasses
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from chipbench import harness, spec
-from chipbench_testing import REPO, run_cell, tiny_root
+from chipbench_testing import HERE, REPO, SRC, run_cell, tiny_root
 import control
 
 from repro.core import engine, state as S, sweep
@@ -40,8 +43,8 @@ def half_grid(batch, vm_p, task_p, **kw):
     h = batch.time.shape[0] // 2
     ran = REAL_GRID(jax.tree.map(lambda x: x[:h], batch), vm_p, task_p, **kw)
     rest = unchanged_grid(jax.tree.map(lambda x: x[h:], batch), vm_p, task_p)
-    return jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=1),
-                        ran, rest)
+    return jax.tree.map(lambda a, b: jnp.concatenate(
+        [a, jax.device_put(b, a.sharding)], axis=1), ran, rest)
 
 
 def no_exchange_grid(batch, vm_p, task_p, **kw):
@@ -97,6 +100,54 @@ def test_single_run_fault_is_caught(tiny, monkeypatch, fault):
             "answer_altered": lambda dc, **k: altered(REAL_RUN(dc, **k))}
     monkeypatch.setattr(engine, "run", fake[fault])
     result = run_cell(*tiny, "fig89.single")
+    assert result["correct"] is False, (fault, result["checks"])
+
+
+FOUR_DEVICE_RUNS = """
+import json, pathlib, sys
+import test_chipbench_faults as t
+out = {"sound": t.run_cell(*t.tiny_root(pathlib.Path(sys.argv[1])),
+                           "sweep.grid_4chip")}
+for name, fault in sorted(t.GRID_FAULTS.items()):
+    t.sweep.run_grid = fault
+    try:
+        out[name] = t.run_cell(*t.tiny_root(pathlib.Path(sys.argv[1]) / name),
+                               "sweep.grid_4chip")
+    finally:
+        t.sweep.run_grid = t.REAL_GRID
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_device_runs(tmp_path_factory):
+    """The four-chip cell's tiny runs on four CPU devices, in a process of
+    their own (JAX fixes its device count when it starts): a sound run,
+    and one under each grid fault, on ``run_grid``'s default partitioner
+    over a mesh of four."""
+    tmp = tmp_path_factory.mktemp("four_devices")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_COMPILATION_CACHE_DIR=str(tmp / "cache"),
+               PYTHONPATH=os.pathsep.join([HERE, SRC]))
+    proc = subprocess.run([sys.executable, "-c", FOUR_DEVICE_RUNS, str(tmp)],
+                          env=env, cwd=HERE, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_four_chip_sound_run_is_correct(four_device_runs):
+    result = four_device_runs["sound"]
+    assert result["correct"] is True, result["checks"]
+    assert result["failed"] == 0 and result["attempted"] >= 1
+    assert result["device"]["count"] == 4
+    assert set(result["metrics"]) == {"setup_s", "cloudlets_per_s"}
+
+
+@pytest.mark.parametrize("fault", sorted(GRID_FAULTS))
+def test_four_chip_fault_is_caught(four_device_runs, fault):
+    result = four_device_runs[fault]
     assert result["correct"] is False, (fault, result["checks"])
 
 

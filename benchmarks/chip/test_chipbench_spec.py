@@ -1,13 +1,14 @@
 """Every cell of BENCHMARK.json resolves to its files; a new cell, mix,
 deployment or metric is found by its name, with no file edited."""
+import hashlib
 import json
 import os
 import re
+import shutil
 
 import pytest
 
-from chipbench import harness, spec
-from chipbench.traffic import Mix
+from chipbench import check, harness, spec
 from chipbench_testing import REPO, run_cell, tiny_root
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -37,13 +38,13 @@ def test_every_cell_resolves(bench):
         assert {"setup_s", "cloudlets_per_s"} <= names
         assert cell.per_layer, w["name"]
         assert all(callable(m.read) for m in cell.end_to_end + cell.per_layer)
-        mixes = [Mix(cell.config, cell.traffic, seed)
+        mixes = [cell.kind.make_mix(cell.config, cell.traffic, seed)
                  for seed in (2**31 + 5, 2**33 + 7)]
-        # the seed orders the pairs and changes no work
+        # the seed orders the work and changes none of it
         work = [sorted(p for i in range(8) for p in m.study(i))
                 for m in mixes]
         assert work[0] == work[1] and work[0]
-        assert mixes[0].replicates >= 1
+        assert mixes[0].lanes(mixes[0].study(0)) >= 1
         assert set(cell.config["checks"]) == {
             "placements_wrong", "states_wrong", "time_rel_err",
             "energy_rel_err", "lanes_short"}
@@ -91,11 +92,19 @@ def test_readers_of_the_whole_window():
     assert read["compile_s"](rec) == 2.5
 
 
-def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax):
+@pytest.mark.parametrize("kind", ["waves", "copied_kind"])
+def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax, kind):
+    """A configuration, a mix, a metric and a cell added as files and
+    entries; under ``copied_kind`` the configuration names a deployment
+    kind of its own, ``deployments/waves.py`` copied under a new name."""
     root, bench_dir = tiny_root(tmp_path)
     with open(os.path.join(bench_dir, "configs", "paper_fig89.json")) as f:
         dep = json.load(f)
     dep["hosts"]["count"] = 8
+    if kind != "waves":
+        dep["generator"] = kind
+        shutil.copy(os.path.join(bench_dir, "deployments", "waves.py"),
+                    os.path.join(bench_dir, "deployments", f"{kind}.py"))
     with open(os.path.join(bench_dir, "configs", "small_dc.json"), "w") as f:
         json.dump(dep, f)
     with open(os.path.join(bench_dir, "traffic", "time_shared.json"),
@@ -119,8 +128,11 @@ def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax):
          "bound": 0.25, "source": "host_clock", "workloads": ["small.time"]})
     with open(path, "w") as f:
         json.dump(bench, f)
+    before = chipbench_digest()
     cell = spec.load_cell(root, "small.time", bench_dir)
     assert cell.config["hosts"]["count"] == 8
+    assert cell.kind.__file__ == os.path.join(bench_dir, "deployments",
+                                              f"{kind}.py")
     assert [m.name for m in cell.end_to_end][-1] == "studies_run"
     result = run_cell(root, bench_dir, "small.time")
     assert result["correct"] is True
@@ -128,3 +140,114 @@ def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax):
     # the cells already there do not report the new metric
     other = spec.load_cell(root, "fig89.single", bench_dir)
     assert "studies_run" not in {m.name for m in other.end_to_end}
+    assert chipbench_digest() == before
+
+
+def chipbench_digest():
+    """A digest of the harness's own files."""
+    h = hashlib.sha256()
+    pkg = os.path.join(spec.BENCH_DIR, "chipbench")
+    for name in sorted(os.listdir(pkg)):
+        if name.endswith(".py"):
+            with open(os.path.join(pkg, name), "rb") as f:
+                h.update(name.encode() + f.read())
+    return h.hexdigest()
+
+
+def test_a_missing_kind_fails_loudly(tmp_path):
+    root, bench_dir = tiny_root(tmp_path)
+    path = os.path.join(bench_dir, "configs", "paper_fig89.json")
+    with open(path) as f:
+        dep = json.load(f)
+    dep["generator"] = "no_such_kind"
+    with open(path, "w") as f:
+        json.dump(dep, f)
+    with pytest.raises(FileNotFoundError, match="no_such_kind"):
+        spec.load_cell(root, "sweep.grid", bench_dir)
+
+
+@pytest.mark.parametrize("readings", [
+    {"a": 0, "b": 0.0},                 # a check with no reading
+    {"a": 0, "b": 0.0, "c": 0, "d": 1},  # a reading with no check
+    {"a": 0, "c": 0},                   # both
+])
+def test_verdict_refuses_readings_and_checks_that_differ(readings):
+    limits = {"a": 0, "b": 1e-3, "c": 0}
+    with pytest.raises(check.Mismatch):
+        check.verdict(readings, limits)
+    correct, table = check.verdict({"a": 0, "b": 2e-3, "c": 0}, limits)
+    assert correct is False and list(table) == ["a", "b", "c"]
+    assert table["b"] == {"value": 2e-3, "limit": 1e-3}
+
+
+# Each existing mix's first 8 studies for three seeds, as the harness
+# before deployment kinds made them: a study's pairs (vm, task) as the
+# digits 2 * vm + task, in lane order.
+PINNED_STUDIES = {
+    ("sweep.grid", 1): ["3102", "2310", "3201", "2130", "2031", "2103",
+                        "1203", "1230"],
+    ("sweep.grid", 2**31 + 11): ["0123", "0213", "1032", "0132", "1302",
+                                 "3120", "3012", "3201"],
+    ("sweep.grid", 2**33 + 7): ["3021", "0321", "2013", "1023", "3021",
+                                "3102", "3012", "3102"],
+    ("fig89.single", 1): ["3", "1", "0", "2", "2", "3", "1", "0"],
+    ("fig89.single", 2**31 + 11): ["0", "1", "2", "3", "0", "2", "1", "3"],
+    ("fig89.single", 2**33 + 7): ["3", "0", "2", "1", "0", "3", "2", "1"],
+}
+
+
+@pytest.mark.parametrize("workload,seed", sorted(PINNED_STUDIES))
+def test_studies_pinned(workload, seed):
+    cell = spec.load_cell(REPO, workload)
+    mix = cell.kind.make_mix(cell.config, cell.traffic, seed)
+    got = ["".join(str(2 * v + t) for v, t in mix.study(i))
+           for i in range(8)]
+    assert got == PINNED_STUDIES[workload, seed]
+
+
+def state_digest(tree):
+    import jax
+    import numpy as np
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(f"{a.dtype}{a.shape}".encode() + a.tobytes())
+    return h.hexdigest()[:16]
+
+
+# The tiny deployment's state as the harness before deployment kinds
+# built it (the template), and the inputs of studies 0 and 1 (seed 5).
+PINNED_STATES = {
+    "sweep.grid": ("b1559e825f12b613", "e50a051f2526cc1b",
+                   "a9c590431635653d"),
+    "fig89.single": ("cddfd0a3ce4fe0ff", "d0028b5c4309bc76",
+                     "cc2121ba3d6af9a2"),
+}
+PINNED_CALLS = {"sweep.grid": ("run_grid", {"max_steps": 8192,
+                                            "sharded": False}),
+                "fig89.single": ("run", {"max_steps": 8192})}
+
+
+@pytest.mark.parametrize("workload", sorted(PINNED_STATES))
+def test_state_and_program_calls_pinned(tmp_path, monkeypatch, workload):
+    """The same ``DatacenterState`` built, and the same program call with
+    the same arguments, as before deployment kinds."""
+    import jax
+    from repro.core import engine, sweep
+    root, bench_dir = tiny_root(tmp_path)
+    cell = spec.load_cell(root, workload, bench_dir)
+    # the traffic's own budget, not the tiny cut's
+    assert cell.traffic["max_steps"] == 8192
+    mix = cell.kind.make_mix(cell.config, cell.traffic, 5)
+    system = cell.kind.System(mix, jax.devices()[:1])
+    got = (state_digest(system.template),
+           state_digest(system.prepare(mix.study(0))),
+           state_digest(system.prepare(mix.study(1))))
+    assert got == PINNED_STATES[workload]
+    calls = []
+    for module, name in ((engine, "run"), (sweep, "run_grid")):
+        monkeypatch.setattr(module, name, lambda *a, _n=name, **k:
+                            calls.append((_n, k)))
+    system.dispatch(system.prepare(mix.study(0)))
+    assert calls == [PINNED_CALLS[workload]]

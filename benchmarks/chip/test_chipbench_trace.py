@@ -10,7 +10,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 # reader of each per-layer metric, found as the harness finds it
 READ = {name: spec._reader(HERE, name)
-        for name in ("study_device_ms", "device_idle_pct")}
+        for name in ("study_device_ms", "device_idle_pct",
+                     "chip_busy_spread_pct")}
 
 
 def test_merge_covered_gaps():
@@ -63,6 +64,17 @@ def test_per_layer_readers_on_two_chips():
     assert READ["study_device_ms"](rec) == pytest.approx(70e-9 * 1e3)
     assert READ["device_idle_pct"](rec) == pytest.approx(
         100 * ((1 - 140 / 220) + (1 - 70 / 220)) / 2)
+
+
+def test_chip_busy_spread_on_two_chips():
+    """Chip 1 runs 35 ns of chip 0's 70 in each study: a spread of 50%;
+    on one chip there is nothing to read."""
+    tr = two_chip_trace()
+    assert READ["chip_busy_spread_pct"](record_of(tr)) == pytest.approx(50.0)
+    tr.busy[1] = [(20, 90), (140, 175)]      # 70 and 35 ns: 0% and 50%
+    assert READ["chip_busy_spread_pct"](record_of(tr)) == pytest.approx(25.0)
+    one = tracing.Trace(tr.chips[:1], tr.busy[:1], tr.op_ns[:1], tr.spans)
+    assert READ["chip_busy_spread_pct"](record_of(one)) is None
 
 
 def test_per_layer_readers_find_nothing_without_a_trace():
