@@ -11,8 +11,11 @@ window began.  With ``--trace 1`` the profiler records the studies that
 start in the window's first ``TRACE_SECONDS``, and the run reports the
 per-layer metrics; with ``--trace 0`` it reports the end-to-end ones.
 Once the window has closed, the kind compares the studies' outputs with
-its plain reference, and ``chipbench.check`` holds each number to its
-limit.
+its plain reference, ``chipbench.check`` holds each number to its limit,
+and the kind reads each study's counters from the same outputs.  The
+metric readers then read the ``Record``: the host clock's readings, each
+study's counters and, in a traced run, the ``tracing.Trace`` of the one
+profile, read before its directory is removed.
 
 The last line on standard output is one JSON object; the last lines on
 standard error are the compared numbers beside their limits.
@@ -27,7 +30,7 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 from chipbench import check, spec, tracing
 from chipbench.clock import CompileClock
@@ -43,6 +46,9 @@ class StudyRun:
     end: float
     cloudlets: int              # completed, summed over the study's lanes
     lanes_short: int
+    # the program's counters of the study, named by the deployment kind
+    # (``counters``), read from its kept outputs after the window
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -167,6 +173,9 @@ def _run_cell(args, t_start, cell, devices, clock) -> dict:
     del kept, system
     readings = kind.readings(mix, studies, outputs)
     readings["lanes_short"] = sum(r.lanes_short for r in runs)
+    for r, counters in zip(runs, kind.counters(mix, studies, outputs),
+                           strict=True):
+        r.counters = counters
     n_checked = sum(mix.lanes(s) for s in studies)
     correct, table = check.verdict(readings, cell.config["checks"])
     correct = correct and n_checked > 0

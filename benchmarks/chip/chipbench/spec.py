@@ -6,7 +6,10 @@ A cell (an entry of ``workloads``) names a configuration, whose ``file``
 its deployment kind (``"generator"``, ``waves`` where it names none),
 whose module ``deployments/<kind>.py`` holds everything that belongs to
 that kind of deployment.  Every metric, end to end or per layer, is read
-by ``metrics/<name>.py``, a module with ``read(record) -> float | None``.
+by ``metrics/<name>.py``, a module with ``read(record) -> float | None``
+(``harness.Record``: the host clock's readings, each study's counters
+and, in a traced run, the ``tracing.Trace``); a reader names the spans,
+scopes and counters it reads.
 Adding a cell, a mix, a deployment kind or a metric adds files and
 entries; no file here changes.
 
@@ -21,13 +24,19 @@ A deployment kind's module provides what the harness calls:
     public API: ``prepare(study)``, ``dispatch(inputs)`` (which entry of
     the program it calls is the kind's choice), ``summary(out)`` (fetched
     to the host: cloudlets completed and lanes that fell short),
-    ``keep(out)`` (the leaves the comparison reads) and ``peak_bytes()``.
+    ``keep(out)`` (the leaves the comparison and ``counters`` read) and
+    ``peak_bytes()``.
 ``reference(lane, precision)``
     the plain reference of one lane; it imports nothing of the program,
     and ``precision="bfloat16"`` makes the control.
 ``readings(mix, studies, outputs)``
     the compared numbers, a dict with the same names as the
     configuration's ``checks`` but ``lanes_short``, which the harness adds.
+``counters(mix, studies, outputs)``
+    one dict of named integers per study, the program's own counts of its
+    work (loop trips, events, admissions, ...), read on the host from the
+    kept outputs after the window, as ``readings`` is; the harness hangs
+    each on its study (``StudyRun.counters``) for the metric readers.
 ``reference_outputs(mix, studies, precision)``
     the studies' kept outputs as the reference in ``precision`` makes
     them, for the control.

@@ -26,8 +26,9 @@ Keys of a traffic file:
     the runner's event budget per lane.
 
 The module fills the contract of a deployment kind (``chipbench.spec``):
-``make_mix``, ``System``, ``reference``, ``readings``,
-``reference_outputs`` and ``tiny``.  The reference and the comparison:
+``make_mix``, ``System``, ``reference``, ``readings``, ``counters``,
+``reference_outputs`` and ``tiny``; ``SCOPES`` names the program's pass
+scopes for ``record_trace.py``'s breakdown.  The reference and the comparison:
 after the window has closed, the plain reference replays the scenario
 once under each policy pair, and every lane of every study the window ran
 is compared with the replay of its pair, slot by slot: VM placements and
@@ -47,6 +48,11 @@ from chipbench.check import rel_err
 
 RUNNERS = ("engine.run", "sweep.run_grid")
 Pair = Tuple[int, int]
+# the named scopes ``engine.step`` and ``engine.batched_run`` run their
+# passes under
+SCOPES = ("events", "autoscaler", "provision", "phases", "rates",
+          "migration", "flows", "horizon", "commit", "probes", "leap",
+          "record", "freeze")
 
 
 # -- the generator: a deployment file and a traffic file make studies -----
@@ -177,13 +183,17 @@ def tiny(config: dict, traffic: dict) -> Tuple[dict, dict]:
 
 class Outputs(NamedTuple):
     """What the comparison reads of a study's final state, [P, R, ...]
-    for a grid and unbatched for a single run."""
+    for a grid and unbatched for a single run, and the program's loop
+    counters (``engine.RunStats``), which ``counters`` reads; the
+    reference makes no counters."""
     cl_state: object
     start_time: object
     finish_time: object
     vm_host: object
     energy_j: object
     time: object
+    iterations: object = None   # loop trips while the lane was live
+    events: object = None       # events retired
 
 
 class System:
@@ -245,30 +255,36 @@ class System:
         return self.template, vm_p, task_p
 
     def dispatch(self, inputs):
+        """``(final state, RunStats)``: the program's loop counters come
+        back beside the state (``stats=True``; the same compiled program
+        runs either way) and stay on the chip until the window closes."""
         dc, vm_p, task_p = inputs
+        steps = self.mix.max_steps
         if self.mix.runner == "engine.run":
-            return self.engine.run(dc, max_steps=self.mix.max_steps)
-        if self.mesh is None:
-            return self.sweep.run_grid(dc, vm_p, task_p,
-                                       max_steps=self.mix.max_steps,
-                                       sharded=False)
-        return self.sweep.run_grid(dc, vm_p, task_p,
-                                   max_steps=self.mix.max_steps,
-                                   mesh=self.mesh)
+            return self.engine.run(dc, max_steps=steps, stats=True)
+        where = {"sharded": False} if self.mesh is None else \
+            {"mesh": self.mesh}
+        return self.sweep.run_grid(dc, vm_p, task_p, max_steps=steps,
+                                   stats=True, **where)
 
     def summary(self, out) -> Tuple[int, int]:
         """The study's summary, fetched to the host: (cloudlets completed,
         summed over lanes; lanes that did not complete every cloudlet)."""
-        n_done = self.jax.device_get(self.sweep.summarize_batch(out)).n_done
+        final, _ = out
+        n_done = self.jax.device_get(
+            self.sweep.summarize_batch(final)).n_done
         return (int(n_done.sum()),
                 int(np.sum(np.asarray(n_done) != len(self.mix.cloudlets.vm))))
 
     @staticmethod
     def keep(out) -> Outputs:
-        """The leaves the comparison reads; the rest of ``out`` is freed."""
-        return Outputs(out.cloudlets.state, out.cloudlets.start_time,
-                       out.cloudlets.finish_time, out.vms.host,
-                       out.hosts.energy_j, out.time)
+        """The leaves the comparison reads and the loop counters; the rest
+        of ``out`` is freed."""
+        final, stats = out
+        return Outputs(final.cloudlets.state, final.cloudlets.start_time,
+                       final.cloudlets.finish_time, final.vms.host,
+                       final.hosts.energy_j, final.time, stats.iterations,
+                       stats.events)
 
     def peak_bytes(self) -> int:
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -623,6 +639,15 @@ def readings(mix: Mix, studies: Sequence[Tuple[Pair, ...]],
     return worst
 
 
+def counters(mix: Mix, studies: Sequence[Tuple[Pair, ...]],
+             outputs: Sequence[Outputs]) -> List[Dict[str, int]]:
+    """Each study's loop counters, from its kept ``RunStats`` (host
+    arrays): ``iterations``, the loop trips of the lane that ran longest,
+    and ``events``, the events retired summed over its lanes."""
+    return [{"iterations": int(np.max(out.iterations)),
+             "events": int(np.sum(out.events))} for out in outputs]
+
+
 def reference_outputs(mix: Mix, studies: Sequence[Tuple[Pair, ...]],
                       precision: str) -> List[Outputs]:
     """Each study's ``Outputs`` as the reference in ``precision`` makes
@@ -638,5 +663,7 @@ def reference_outputs(mix: Mix, studies: Sequence[Tuple[Pair, ...]],
             return rows[0]
         return np.broadcast_to(np.stack(rows)[:, None],
                                (len(pairs), mix.replicates) + rows[0].shape)
-    return [Outputs(*(lanes(study, leaf) for leaf in Outputs._fields))
+    compared = [f for f in Outputs._fields
+                if f not in Outputs._field_defaults]     # not the counters
+    return [Outputs(*(lanes(study, leaf) for leaf in compared))
             for study in studies]

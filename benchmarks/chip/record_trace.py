@@ -7,19 +7,21 @@ pass scopes and loop counters in it.
 Builds the cell's deployment (cut to ``--hosts`` hosts where given),
 warms up, then runs ``--studies`` studies under the profiler, each in the
 harness's ``study`` / ``prepare`` / ``dispatch`` / ``wait`` / ``fetch``
-spans and with the program's loop counters on (``stats=True``), and as
-many again with the profiler off.  Prints one JSON line: per study the
-loop trips and events, the readings of ``chipbench.program_trace``, the
-device ms of each pass per study, the idle gaps by host piece, and the
-mean study time traced and untraced.  With ``--out`` the profile lands in
-``<out>/trace.xplane.pb``, beside ``studies.json`` (the traced studies
-and the chips' device ids); source files in its op metadata are named
-relative to the checkout.  Needs a TPU, as ``run.py`` does (exit 3
-without one).
+spans, and as many again with the profiler off.  It calls the deployment
+kind as the harness does (``make_mix``, ``System``, ``keep`` and, once
+the studies are done, ``counters``), and reads the profile with the
+harness's own ``tracing.load`` and metric readers.  Prints one JSON line:
+per study its counters, the reading of every per-layer metric of the
+cell, the device ms of each pass scope the kind names (``SCOPES``) per
+study, innermost scope first and ``other`` for the rest, the idle gaps by
+host piece, and the mean study time traced and untraced.  With ``--out``
+the profile lands in ``<out>/trace.xplane.pb``, beside ``studies.json``
+(the traced studies and the chips' device ids); source files in its op
+metadata are named relative to the checkout.  Needs a TPU, as ``run.py``
+does (exit 3 without one).
 """
 import argparse
 import dataclasses
-import glob
 import json
 import os
 import re
@@ -32,57 +34,62 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path[:0] = [HERE, os.path.join(REPO, "src")]
 
-from chipbench import program_trace, spec, tracing  # noqa: E402
+from chipbench import harness, spec, tracing  # noqa: E402
 
 SEED = 2**31 + 101
+OTHER = "other"
 
 
-@dataclasses.dataclass
-class Study:
-    index: int
-    start: float                # host clock, s
-    end: float
-    cloudlets: int              # completed, summed over the study's lanes
-    lanes_short: int
-    iterations: int             # loop trips, the most of any lane
-    events: int                 # events retired, summed over lanes
-
-
-def dispatch_counted(system, inputs):
-    """``System.dispatch`` of the ``waves`` deployment kind with the
-    program's loop counters on: ``(final state, RunStats)``."""
-    dc, vm_p, task_p = inputs
-    steps = system.mix.max_steps
-    if system.mix.runner == "engine.run":
-        return system.engine.run(dc, max_steps=steps, stats=True)
-    where = {"sharded": False} if system.mesh is None else \
-        {"mesh": system.mesh}
-    return system.sweep.run_grid(dc, vm_p, task_p, max_steps=steps,
-                                 stats=True, **where)
-
-
-def run_studies(jax, system, mix, first: int, count: int):
-    """``count`` studies from index ``first``, as the harness runs them."""
-    import numpy as np
+def run_studies(jax, kind, system, mix, first: int, count: int):
+    """``count`` studies from index ``first``, as the harness runs them,
+    each with its counters."""
     span = jax.profiler.TraceAnnotation
-    out = []
+    runs, studies, kept = [], [], []
     for i in range(first, first + count):
         with span(tracing.SPAN_STUDY, index=i):
             s0 = time.perf_counter()
             with span("prepare"):
-                dc = system.prepare(mix.study(i))
+                study = mix.study(i)
+                dc = system.prepare(study)
             with span("dispatch"):
-                final, stats = dispatch_counted(system, dc)
+                out = system.dispatch(dc)
             with span("wait"):
-                jax.block_until_ready(final)
+                jax.block_until_ready(out)
             with span("fetch"):
-                cloudlets, short = system.summary(final)
+                cloudlets, short = system.summary(out)
             s1 = time.perf_counter()
-        stats = jax.device_get(stats)
-        out.append(Study(i, s0, s1, cloudlets, short,
-                         int(np.max(stats.iterations)),
-                         int(np.sum(stats.events))))
-    return out
+        kept.append(system.keep(out))
+        studies.append(study)
+        runs.append(harness.StudyRun(i, s0, s1, cloudlets, short))
+    counts = kind.counters(mix, studies, jax.device_get(kept))
+    for r, c in zip(runs, counts, strict=True):
+        r.counters = c
+    return runs
+
+
+def as_dict(run: harness.StudyRun) -> dict:
+    """A study as ``studies.json`` holds it, its counters among its
+    fields."""
+    d = dataclasses.asdict(run)
+    return {**d, **d.pop("counters")}
+
+
+def passes_ms(trace: tracing.Trace, names) -> dict:
+    """Device ms per traced study of each scope in ``names``, by the
+    innermost of them in each leaf operation's name path (``other`` where
+    none is), the busiest chip for each."""
+    n = max(len(trace.studies()), 1)
+    out = {}
+    for chip in trace.leaf_ns:
+        total = {}
+        for study in chip:
+            for path, ns in study.items():
+                k = next((s for s in reversed(tracing.scopes(path))
+                          if s in names), OTHER)
+                total[k] = total.get(k, 0.0) + ns
+        for k, ns in total.items():
+            out[k] = max(out.get(k, 0.0), 1e-6 * ns / n)
+    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
 def record(a, require_tpu: bool = True):
@@ -95,44 +102,42 @@ def record(a, require_tpu: bool = True):
     if require_tpu and jax.devices()[0].platform != "tpu":
         print("[record] refusing to run: no TPU", file=sys.stderr)
         return 3, None
-    mix = cell.kind.make_mix(cell.config, cell.traffic, SEED)
-    system = cell.kind.System(mix, jax.devices()[:cell.chips])
-    warm, _ = dispatch_counted(system, system.prepare(mix.warmup()))
-    system.summary(warm)
-    del warm
+    kind = cell.kind
+    mix = kind.make_mix(cell.config, cell.traffic, SEED)
+    system = kind.System(mix, jax.devices()[:cell.chips])
+    system.summary(system.dispatch(system.prepare(mix.warmup())))
 
     trace_dir = tempfile.mkdtemp(prefix="chipbench-record-")
     try:
         jax.profiler.start_trace(trace_dir)
-        traced = run_studies(jax, system, mix, 0, a.studies)
+        traced = run_studies(jax, kind, system, mix, 0, a.studies)
         jax.profiler.stop_trace()
-        untraced = run_studies(jax, system, mix, a.studies, a.studies)
-        path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                          recursive=True)
+        untraced = run_studies(jax, kind, system, mix, a.studies, a.studies)
+        path = tracing.trace_file(trace_dir)
         device_ids = [d.id for d in system.devices]
-        pt = program_trace.load_file(path, device_ids)
+        trace = tracing.load_file(path, device_ids)
+        as_dicts = [as_dict(r) for r in traced]
         if a.out:
             os.makedirs(a.out, exist_ok=True)
             shutil.copy(path, os.path.join(a.out, "trace.xplane.pb"))
             with open(os.path.join(a.out, "studies.json"), "w") as f:
-                json.dump({"device_ids": device_ids,
-                           "studies": [dataclasses.asdict(s)
-                                       for s in traced]}, f, indent=1)
+                json.dump({"device_ids": device_ids, "studies": as_dicts},
+                          f, indent=1)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    per_study = pt.trace.study_busy_s()
+    rec = harness.Record(cell.chips, setup_s=None, build_s=None,
+                         compile_s=None, cache_hits=0, studies=traced,
+                         memory_peak_bytes=system.peak_bytes(), trace=trace)
+    studies = trace.studies()
     mean_ms = lambda ss: 1e3 * sum(s.end - s.start for s in ss) / len(ss)
     result = {
         "workload": a.workload, "hosts": int(cell.config["hosts"]["count"]),
-        "studies": [dataclasses.asdict(s) for s in traced],
-        "readings": pt.readings([s.iterations for s in traced]),
-        "study_device_ms": (1e3 * sum(map(max, per_study)) / len(per_study)
-                            if pt.trace.chips else None),
-        "passes_ms": dict(sorted(pt.pass_ms().items(),
-                                 key=lambda kv: -kv[1])),
-        "idle_ms_by_host": {k: 1e3 * v / len(per_study)
-                            for k, v in pt.trace.idle_by_host()},
+        "studies": as_dicts,
+        "readings": {m.name: m.read(rec) for m in cell.per_layer},
+        "passes_ms": passes_ms(trace, getattr(kind, "SCOPES", ())),
+        "idle_ms_by_host": {k: 1e3 * v / len(studies)
+                            for k, v in trace.idle_by_host()},
         "study_ms_traced": mean_ms(traced),
         "study_ms_untraced": mean_ms(untraced),
     }

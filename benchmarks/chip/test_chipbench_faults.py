@@ -30,11 +30,19 @@ def tiny(tmp_path, monkeypatch):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", before)
 
 
+def no_trips(shape=()):
+    """Loop counters of a run that made no loop trip."""
+    zeros = jnp.zeros(shape, jnp.int32)
+    return engine.RunStats(iterations=zeros, events=zeros)
+
+
 def unchanged_grid(batch, vm_p, task_p, **_):
-    """A step that returns its state unchanged, under every policy."""
+    """A step that returns its state unchanged, under every policy; the
+    counters (asked for with ``stats=True``) count no trip."""
     n = vm_p.shape[0]
-    return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
-                        batch)
+    out = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
+                       batch)
+    return out, no_trips((n, batch.time.shape[0]))
 
 
 def half_grid(batch, vm_p, task_p, **kw):
@@ -60,13 +68,14 @@ def no_exchange_grid(batch, vm_p, task_p, **kw):
     return jax.tree.map(first_chip_everywhere, out)
 
 
-def altered(out):
+def altered(result):
     """Every completed cloudlet's finish time 1% late, where it is made."""
+    out, stats = result
     cl = out.cloudlets
     ft = jnp.where(cl.state == S.CL_DONE, cl.finish_time * 1.01,
                    cl.finish_time)
     return dataclasses.replace(out, cloudlets=dataclasses.replace(
-        cl, finish_time=ft))
+        cl, finish_time=ft)), stats
 
 
 @pytest.mark.parametrize("workload", ["sweep.grid", "fig89.single"])
@@ -96,7 +105,7 @@ def test_grid_fault_is_caught(tiny, monkeypatch, fault):
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "answer_altered"])
 def test_single_run_fault_is_caught(tiny, monkeypatch, fault):
-    fake = {"state_unchanged": lambda dc, **_: dc,
+    fake = {"state_unchanged": lambda dc, **_: (dc, no_trips()),
             "answer_altered": lambda dc, **k: altered(REAL_RUN(dc, **k))}
     monkeypatch.setattr(engine, "run", fake[fault])
     result = run_cell(*tiny, "fig89.single")

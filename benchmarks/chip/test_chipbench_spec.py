@@ -92,11 +92,54 @@ def test_readers_of_the_whole_window():
     assert read["compile_s"](rec) == 2.5
 
 
-@pytest.mark.parametrize("kind", ["waves", "copied_kind"])
+# A deployment kind's own span and counter, appended to a copy of
+# ``deployments/waves.py``: its program runs under the host span ``admit``
+# and counts ``admissions``, names no kind has today.
+OWN_SPAN_AND_COUNTER = """
+
+import jax as _jax
+
+_Waves = System
+_waves_counters = counters
+
+
+class System(_Waves):
+    def dispatch(self, inputs):
+        with _jax.profiler.TraceAnnotation("admit"):
+            return super().dispatch(inputs)
+
+
+def counters(mix, studies, outputs):
+    return [dict(c, admissions=mix.lanes(s)) for c, s
+            in zip(_waves_counters(mix, studies, outputs), studies)]
+"""
+
+# A per-layer metric of that span and counter, as a file of its own.
+ADMIT_READER = """
+import statistics
+
+
+def read(record):
+    tr = record.trace
+    if tr is None or "admit" not in tr.host:
+        return None
+    per_study = []
+    for (s, e), run in zip(tr.studies(), record.studies):
+        ns = sum(min(z, e) - max(a, s) for a, z in tr.host["admit"]
+                 if a < e and z > s)
+        per_study.append(1e-3 * ns / run.counters["admissions"])
+    return statistics.median(per_study)
+"""
+
+
+@pytest.mark.parametrize("kind", ["waves", "copied_kind", "counted_kind"])
 def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax, kind):
     """A configuration, a mix, a metric and a cell added as files and
     entries; under ``copied_kind`` the configuration names a deployment
-    kind of its own, ``deployments/waves.py`` copied under a new name."""
+    kind of its own, ``deployments/waves.py`` copied under a new name;
+    under ``counted_kind`` that kind also runs under a span and counts with
+    a counter of its own, and a new per-layer metric file reads both in a
+    traced run."""
     root, bench_dir = tiny_root(tmp_path)
     with open(os.path.join(bench_dir, "configs", "paper_fig89.json")) as f:
         dep = json.load(f)
@@ -105,6 +148,13 @@ def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax, kind):
         dep["generator"] = kind
         shutil.copy(os.path.join(bench_dir, "deployments", "waves.py"),
                     os.path.join(bench_dir, "deployments", f"{kind}.py"))
+    if kind == "counted_kind":
+        with open(os.path.join(bench_dir, "deployments", f"{kind}.py"),
+                  "a") as f:
+            f.write(OWN_SPAN_AND_COUNTER)
+        with open(os.path.join(bench_dir, "metrics", "admit_host_us.py"),
+                  "w") as f:
+            f.write(ADMIT_READER)
     with open(os.path.join(bench_dir, "configs", "small_dc.json"), "w") as f:
         json.dump(dep, f)
     with open(os.path.join(bench_dir, "traffic", "time_shared.json"),
@@ -126,6 +176,11 @@ def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax, kind):
     bench["end_to_end"].append(
         {"name": "studies_run", "unit": "studies", "better": "higher",
          "bound": 0.25, "source": "host_clock", "workloads": ["small.time"]})
+    if kind == "counted_kind":
+        bench["per_layer"].append(
+            {"name": "admit_host_us", "unit": "us", "better": "lower",
+             "source": "program_span", "layer": "admission",
+             "moves": "studies_run", "workloads": ["small.time"]})
     with open(path, "w") as f:
         json.dump(bench, f)
     before = chipbench_digest()
@@ -140,6 +195,11 @@ def test_a_new_cell_is_found_by_name(tmp_path, quiet_jax, kind):
     # the cells already there do not report the new metric
     other = spec.load_cell(root, "fig89.single", bench_dir)
     assert "studies_run" not in {m.name for m in other.end_to_end}
+    if kind == "counted_kind":
+        traced = run_cell(root, bench_dir, "small.time", trace=1)
+        assert traced["correct"] is True
+        assert traced["metrics"]["admit_host_us"]["value"] > 0.0
+        assert "admit_host_us" not in {m.name for m in other.per_layer}
     assert chipbench_digest() == before
 
 
@@ -225,14 +285,16 @@ PINNED_STATES = {
                      "cc2121ba3d6af9a2"),
 }
 PINNED_CALLS = {"sweep.grid": ("run_grid", {"max_steps": 8192,
+                                            "stats": True,
                                             "sharded": False}),
-                "fig89.single": ("run", {"max_steps": 8192})}
+                "fig89.single": ("run", {"max_steps": 8192, "stats": True})}
 
 
 @pytest.mark.parametrize("workload", sorted(PINNED_STATES))
 def test_state_and_program_calls_pinned(tmp_path, monkeypatch, workload):
     """The same ``DatacenterState`` built, and the same program call with
-    the same arguments, as before deployment kinds."""
+    the same arguments, as before deployment kinds, with the program's
+    loop counters asked for (``stats=True``: the same compiled program)."""
     import jax
     from repro.core import engine, sweep
     root, bench_dir = tiny_root(tmp_path)
@@ -251,3 +313,46 @@ def test_state_and_program_calls_pinned(tmp_path, monkeypatch, workload):
                             calls.append((_n, k)))
     system.dispatch(system.prepare(mix.study(0)))
     assert calls == [PINNED_CALLS[workload]]
+
+
+@pytest.mark.parametrize("workload", ["fig89.single", "sweep.grid"])
+def test_waves_counters_are_the_run_stats(tmp_path, quiet_jax, workload):
+    """The ``waves`` kind's ``counters`` of a study: ``iterations``, the
+    loop trips of its longest lane, and ``events``, summed over its lanes,
+    as ``engine.run(..., stats=True)`` and ``sweep.run_grid(...,
+    stats=True)`` count them when called directly; a grid lane counts
+    what a single run of its pair does."""
+    import dataclasses
+    import jax
+    import numpy as np
+    from repro.core import engine, sweep
+    root, bench_dir = tiny_root(tmp_path)
+    cell = spec.load_cell(root, workload, bench_dir)
+    kind = cell.kind
+    mix = kind.make_mix(cell.config, cell.traffic, 2**31 + 21)
+    system = kind.System(mix, jax.devices()[:1])
+    studies = [mix.study(i) for i in range(2)]
+    kept, direct = [], []
+    one = (system.template if mix.runner == "engine.run" else
+           jax.tree.map(lambda x: x[0], system.template))
+    for study in studies:
+        dc, vm_p, task_p = system.prepare(study)
+        kept.append(system.keep(system.dispatch((dc, vm_p, task_p))))
+        if mix.runner == "engine.run":
+            _, stats = engine.run(dc, max_steps=mix.max_steps, stats=True)
+        else:
+            _, stats = sweep.run_grid(dc, vm_p, task_p, sharded=False,
+                                      max_steps=mix.max_steps, stats=True)
+        stats = jax.device_get(stats)
+        direct.append({"iterations": int(np.max(stats.iterations)),
+                       "events": int(np.sum(stats.events))})
+        single = [jax.device_get(engine.run(
+            dataclasses.replace(one, vm_policy=np.int32(v),
+                                task_policy=np.int32(t)),
+            max_steps=mix.max_steps, stats=True)[1]) for v, t in study]
+        assert direct[-1] == {
+            "iterations": max(int(s.iterations) for s in single),
+            "events": mix.replicates * sum(int(s.events) for s in single)}
+    got = kind.counters(mix, studies, jax.device_get(kept))
+    assert got == direct
+    assert all(c["iterations"] >= 1 and c["events"] >= 1 for c in got)
